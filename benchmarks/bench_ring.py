@@ -2,7 +2,9 @@
 8-CPU-device ring (the cross-chip analogue of Figs. 8/9's per-schedule kernel
 timing).  Runs in a subprocess so the forced device count never leaks into the
 benchmark process; emits CSV rows plus benchmarks/BENCH_ring.json so the perf
-trajectory tracks the new repro.dist subsystem.
+trajectory tracks the new repro.dist subsystem.  The child is pinned to
+``JAX_PLATFORMS=cpu``: a parent that has touched JAX holds the accelerator,
+and these are CPU times, never device metrics.
 
 Expected shape of the result (paper §3.4 economics at CP granularity): under a
 causal mask the zigzag/symmetric-shift layout balances every device at (n+1)/2
@@ -23,9 +25,10 @@ SCRIPT = textwrap.dedent("""
     import os, json, time, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import auto_mesh
     from repro.dist.ring_attention import ring_attention, zigzag_permutation
 
-    mesh = jax.make_mesh((8,), ("cp",))
+    mesh = auto_mesh((8,), ("cp",))
     B, S, H, D = 1, 1024, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, do = (jax.random.normal(kk, (B, S, H, D), jnp.float32) for kk in ks)
@@ -40,7 +43,8 @@ SCRIPT = textwrap.dedent("""
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / iters * 1e6
 
-    results = {"device_count": 8, "B": B, "S": S, "H": H, "D": D, "cases": {}}
+    results = {"platform": jax.devices()[0].platform, "device_count": 8,
+               "B": B, "S": S, "H": H, "D": D, "cases": {}}
     for layout in ("contig", "zigzag"):
         qq, kk_, vv, dd = ((x[:, perm] if layout == "zigzag" else x)
                            for x in (q, k, v, do))
@@ -68,8 +72,11 @@ SCRIPT = textwrap.dedent("""
 def main() -> None:
     r = subprocess.run([sys.executable, "-c", SCRIPT, ART],
                        capture_output=True, text=True, timeout=1200,
-                       env={**os.environ, "PYTHONPATH": "src"},
+                       env={**os.environ, "PYTHONPATH": "src",
+                            "JAX_PLATFORMS": "cpu"},
                        cwd=os.path.join(os.path.dirname(__file__), ".."))
+    print("# bench_ring: CPU run (JAX_PLATFORMS=cpu, 8 forced host devices);"
+          " CPU times, not device metrics", flush=True)
     sys.stdout.write(r.stdout)
     if r.returncode != 0:
         sys.stderr.write(r.stderr)

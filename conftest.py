@@ -1,19 +1,5 @@
-"""Repo-root pytest bootstrap.
-
-Gates optional third-party deps the container may lack: if the real
-``hypothesis`` is importable it is used untouched; otherwise the deterministic
-stub in ``repro._compat.hypothesis_stub`` is aliased in so the property tests
-still run (with a fixed-seed sweep instead of full shrinking).
-"""
+"""Repo-root pytest bootstrap: puts ``src/`` on the import path."""
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "src"))
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro._compat import hypothesis_stub
-
-    sys.modules["hypothesis"] = hypothesis_stub
-    sys.modules["hypothesis.strategies"] = hypothesis_stub.strategies

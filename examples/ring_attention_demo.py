@@ -14,10 +14,11 @@ import numpy as np
 from repro.dist.ring_attention import (ring_attention, zigzag_inverse,
                                        zigzag_permutation)
 from repro.kernels.ops import xla_attention
+from repro.launch.mesh import auto_mesh
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("cp",))
+    mesh = auto_mesh((8,), ("cp",))
     B, S, H, D = 2, 512, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32) for kk in ks)

@@ -97,10 +97,7 @@ def ring_ordered_psum(x: jax.Array, axis_name: str) -> jax.Array:
     bandwidth; use for reproducibility-critical, latency-tolerant reductions
     (e.g. metrics, or full gradients when bitwise elasticity is required).
     """
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:                                   # jax 0.4.x: axis_frame is the size
-        n = jax.core.axis_frame(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     fwd = [(i, (i + 1) % n) for i in range(n)]
 

@@ -21,7 +21,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -87,6 +86,6 @@ def pipeline_apply(stage_fn: Callable, ws, x, mesh: Mesh, axis: str,
     w_specs = jax.tree.map(
         lambda a: P(axis, *([None] * (a.ndim - 1))), ws)
     rep = P(*([None] * x.ndim))
-    fn = shard_map(per_device, mesh=mesh, in_specs=(w_specs, rep),
-                   out_specs=rep, check_rep=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(w_specs, rep),
+                       out_specs=rep, check_vma=False)
     return fn(ws, x)

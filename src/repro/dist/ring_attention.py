@@ -46,7 +46,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import schedules as schedules_mod
@@ -249,9 +248,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
     ring_step_offsets(n, causal)   # derive + assert the DASH step order
 
     spec = P(None, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: _ring_block(q_, k_, v_, axis, n, causal, layout,
                                        scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -13,8 +13,8 @@ Layers:
     axis + TP), ``zero3_pod`` (ZeRO-3 over (pod, data) — the multi-pod
     variant), ``cp`` (context parallel: sequence over the model axis).
   * ``use_rules(rules, mesh)``: context manager activating a rule set; inside
-    it :func:`shard` becomes a ``with_sharding_constraint`` and the compat jit
-    wrapper (below) resolves bare ``PartitionSpec`` shardings against ``mesh``.
+    it :func:`shard` becomes a ``with_sharding_constraint`` (``jax.set_mesh``
+    lets ``jax.jit`` resolve bare ``PartitionSpec`` shardings).
   * ``logical_to_spec`` / ``spec_tree_to_pspecs``: logical axes →
     ``PartitionSpec`` (trees), used by ``train/step.py`` and the dry-run.
   * ``sanitize_pspecs``: drop mesh axes that are absent from the mesh or do
@@ -22,18 +22,10 @@ Layers:
 
 Outside any ``use_rules`` context :func:`shard` is the identity, so pure
 single-device unit tests never touch mesh machinery.
-
-Compat: the repo targets the current ``jax.set_mesh`` API.  On the pinned
-jax 0.4.x this module installs two narrow shims at import time: a
-``jax.set_mesh`` context manager, and a ``jax.jit`` wrapper that converts
-``PartitionSpec`` leaves in ``in_shardings``/``out_shardings`` to
-``NamedSharding`` against the active mesh (0.4.x jit only accepts
-``Sharding`` objects).  Both are no-ops on newer jax.
 """
 from __future__ import annotations
 
 import contextlib
-import functools
 import threading
 from typing import Dict, Optional, Tuple
 
@@ -51,13 +43,6 @@ def _stack():
     return _state.stack
 
 
-def _current_mesh() -> Optional[Mesh]:
-    for rules, mesh in reversed(_stack()):
-        if mesh is not None:
-            return mesh
-    return None
-
-
 def _current_rules_mesh():
     for rules, mesh in reversed(_stack()):
         if rules is not None:
@@ -67,7 +52,7 @@ def _current_rules_mesh():
 
 @contextlib.contextmanager
 def use_rules(rules: Rules, mesh: Mesh):
-    """Activate a logical→mesh rule set for :func:`shard` (and the compat jit)."""
+    """Activate a logical→mesh rule set for :func:`shard`."""
     _stack().append((rules, mesh))
     try:
         yield
@@ -223,36 +208,3 @@ RULE_SETS = {
     "zero3_pod": _zero3_pod,
     "cp": _cp,
 }
-
-
-# ------------------------------------------------------------ jax<0.6 compat
-if not hasattr(jax, "set_mesh"):
-    @contextlib.contextmanager
-    def _set_mesh(mesh: Mesh):
-        """Shim for ``jax.set_mesh`` on jax 0.4.x: records the active mesh so
-        the jit wrapper below can resolve PartitionSpec shardings."""
-        _stack().append((None, mesh))
-        try:
-            yield mesh
-        finally:
-            _stack().pop()
-
-    jax.set_mesh = _set_mesh
-
-    _orig_jit = jax.jit
-
-    def _resolve_specs(tree, mesh: Mesh):
-        return jax.tree.map(
-            lambda x: NamedSharding(mesh, x) if isinstance(x, P) else x,
-            tree, is_leaf=lambda x: isinstance(x, P) or x is None)
-
-    @functools.wraps(_orig_jit)
-    def _jit(fun, **kw):
-        mesh = _current_mesh()
-        if mesh is not None:
-            for key in ("in_shardings", "out_shardings"):
-                if key in kw:
-                    kw[key] = _resolve_specs(kw[key], mesh)
-        return _orig_jit(fun, **kw)
-
-    jax.jit = _jit

@@ -56,10 +56,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):      # named TPUCompilerParams on jax 0.4.x
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 from repro.core.schedules import Schedule
+from repro.kernels.flash_fwd import info_inputs, split_info_refs
 from repro.kernels.gqa import kv_head_index, validate_group
 
 NEG_INF = -1e30
@@ -90,12 +88,26 @@ def first_visit_flags(kv_ids: np.ndarray, q_ids: np.ndarray) -> np.ndarray:
     return flags.astype(np.int32)
 
 
+def dq_lanes(d: int) -> int:
+    """Lane width of the fp32 dQ buffers the kernels DMA into: ``d`` rounded
+    up to 128. The TPU DMA moves whole 128-lane tiles, so at head_dim 64 the
+    (block_q, d) read-modify-write window is refused; the kernels store dQ in
+    the first ``d`` lanes and the wrapper slices the rest away."""
+    return -(-d // 128) * 128
+
+
+def _row_to_col(x):
+    """(1, n) row -> (n, 1) column (inverse of flash_fwd.col_to_row)."""
+    return jnp.broadcast_to(x, (128, x.shape[1])).T[:, :1]
+
+
 # --------------------------------------------------------------------------- #
 # shared task math (one (kv, q) tile of Alg. 1)
 # --------------------------------------------------------------------------- #
 def _task_grads(q, k, v, do, lse, delta, kv, qi, *, sm_scale, causal,
                 block_q, block_k, mask_spec=None, q_info=None, k_info=None):
-    """Compute phase (DAG cost c): p/ds and the three tile contributions."""
+    """Compute phase (DAG cost c): p/ds and the three tile contributions.
+    ``lse``/``delta`` arrive as (block_q, 1) columns."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
     msk = None
@@ -108,7 +120,7 @@ def _task_grads(q, k, v, do, lse, delta, kv, qi, *, sm_scale, causal,
         cols = kv * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         msk = mask_spec.tile_mask(rows, cols, q_info, k_info)
         s = jnp.where(msk, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                                   # (bq, bk)
+    p = jnp.exp(s - lse)                                            # (bq, bk)
     if msk is not None:
         # exact-zero masked lanes (see flash_fwd._fwd_body): PARTIAL tiles
         # contribute literal 0.0 outside the mask, so both realizations stay
@@ -116,7 +128,7 @@ def _task_grads(q, k, v, do, lse, delta, kv, qi, *, sm_scale, causal,
         p = p * msk.astype(jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)    # (bq, bk)
-    ds = p * (dp - delta[:, None]) * sm_scale
+    ds = p * (dp - delta) * sm_scale
     dv_contrib = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
     dk_contrib = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
@@ -130,11 +142,11 @@ def _task_grads(q, k, v, do, lse, delta, kv, qi, *, sm_scale, causal,
 # serialized kernel body (grid = (bh, n_tasks), one core plays every chain)
 # --------------------------------------------------------------------------- #
 def _bwd_kernel(kv_ids, q_ids, q_first,        # scalar prefetch (SMEM)
-                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                qinfo_ref, kinfo_ref,
-                dq_hbm, dk_ref, dv_ref,
-                dq_scratch, sem_in, sem_out,
-                *, sm_scale, causal, block_q, block_k, mask_spec=None):
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                sm_scale, causal, block_q, block_k, mask_spec=None,
+                has_info=False):
+    q_info, k_info, (dq_hbm, dk_ref, dv_ref, dq_scratch, sem_in, sem_out) = \
+        split_info_refs(refs, has_info)
     b = pl.program_id(0)
     t = pl.program_id(1)
     kv = kv_ids[t]
@@ -143,9 +155,9 @@ def _bwd_kernel(kv_ids, q_ids, q_first,        # scalar prefetch (SMEM)
     dq_contrib, dk_contrib, dv_contrib = _task_grads(
         q_ref[0].astype(jnp.float32), k_ref[0].astype(jnp.float32),
         v_ref[0].astype(jnp.float32), do_ref[0].astype(jnp.float32),
-        lse_ref[0], delta_ref[0], kv, qi, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, mask_spec=mask_spec,
-        q_info=qinfo_ref[...], k_info=kinfo_ref[...])
+        _row_to_col(lse_ref[0]), _row_to_col(delta_ref[0]), kv, qi,
+        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+        mask_spec=mask_spec, q_info=q_info, k_info=k_info)
 
     # ---- dV/dK: chain-contiguous accumulation; block stays VMEM-resident ----
     first_of_chain = jnp.logical_or(t == 0, kv_ids[jnp.maximum(t - 1, 0)] != kv)
@@ -162,20 +174,29 @@ def _bwd_kernel(kv_ids, q_ids, q_first,        # scalar prefetch (SMEM)
 
     # ---- dQ: ordered deterministic global reduction (Alg. 1 l.30–36) ----
     # reduction phase (cost r in the DAG model): explicit HBM<->VMEM RMW, order =
-    # serialized schedule order. Semaphore waits pin the order; no implicit
-    # pipelining is involved, so no stale-buffer hazards regardless of schedule.
-    dq_slice = dq_hbm.at[b, pl.ds(qi * block_q, block_q), :]
+    # serialized schedule order.
+    _dq_rmw(dq_hbm.at[b, pl.ds(qi * block_q, block_q), :], dq_scratch,
+            dq_contrib, q_first[t] == 1, sem_in, sem_out)
 
-    @pl.when(q_first[t] == 1)
+
+def _dq_rmw(dq_slice, dq_scratch, dq_contrib, fresh, sem_in, sem_out):
+    """Add ``dq_contrib`` into the HBM window ``dq_slice`` through VMEM.
+
+    Semaphore waits pin the order; no implicit pipelining is involved, so no
+    stale-buffer hazards regardless of schedule. Only the first ``d`` of the
+    window's ``dq_lanes(d)`` lanes carry dQ."""
+    d = dq_contrib.shape[1]
+
+    @pl.when(fresh)
     def _fresh():
-        dq_scratch[...] = dq_contrib
+        dq_scratch[:, :d] = dq_contrib
 
-    @pl.when(q_first[t] == 0)
+    @pl.when(jnp.logical_not(fresh))
     def _rmw():
         cp_in = pltpu.make_async_copy(dq_slice, dq_scratch, sem_in)
         cp_in.start()
         cp_in.wait()
-        dq_scratch[...] += dq_contrib
+        dq_scratch[:, :d] += dq_contrib
 
     cp_out = pltpu.make_async_copy(dq_scratch, dq_slice, sem_out)
     cp_out.start()
@@ -192,13 +213,15 @@ def _flash_bwd_call(q, k, v, do, lse, delta, kv_ids, q_ids, q_first, causal,
     sk = k.shape[1]
     n_tasks = int(kv_ids.shape[0])
     grid = (bh, n_tasks)
+    dp = dq_lanes(d)
+    info_specs, info_args = info_inputs(
+        mask, sq, lambda b, t, kvi, qi, qf: (qi[t], 0),
+        lambda b, t, kvi, qi, qf: (0, kvi[t]), block_q, block_k)
     kernel = functools.partial(
         _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, mask_spec=mask)
+        block_k=block_k, mask_spec=mask, has_info=bool(info_args))
     kvb = functools.partial(kv_head_index, n_heads=n_heads,
                             n_kv_heads=n_kv_heads)
-    info = mask.token_info(sq) if mask is not None else None
-    info = np.zeros((sq,), np.int32) if info is None else info
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -210,18 +233,16 @@ def _flash_bwd_call(q, k, v, do, lse, delta, kv_ids, q_ids, q_first, causal,
             pl.BlockSpec((1, block_k, d),
                          lambda b, t, kvi, qi, qf: (kvb(b), kvi[t], 0)),
             pl.BlockSpec((1, block_q, d), lambda b, t, kvi, qi, qf: (b, qi[t], 0)),
-            pl.BlockSpec((1, block_q), lambda b, t, kvi, qi, qf: (b, qi[t])),
-            pl.BlockSpec((1, block_q), lambda b, t, kvi, qi, qf: (b, qi[t])),
-            pl.BlockSpec((block_q,), lambda b, t, kvi, qi, qf: (qi[t],)),
-            pl.BlockSpec((block_k,), lambda b, t, kvi, qi, qf: (kvi[t],)),
-        ],
+            pl.BlockSpec((1, 1, block_q), lambda b, t, kvi, qi, qf: (b, 0, qi[t])),
+            pl.BlockSpec((1, 1, block_q), lambda b, t, kvi, qi, qf: (b, 0, qi[t])),
+        ] + info_specs,
         out_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # dq: explicit DMA RMW
             pl.BlockSpec((1, block_k, d), lambda b, t, kvi, qi, qf: (b, kvi[t], 0)),
             pl.BlockSpec((1, block_k, d), lambda b, t, kvi, qi, qf: (b, kvi[t], 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dp), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
@@ -231,15 +252,14 @@ def _flash_bwd_call(q, k, v, do, lse, delta, kv_ids, q_ids, q_first, causal,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, dp), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_ids, q_ids, q_first, q, k, v, do, lse, delta,
-      jnp.asarray(info), jnp.asarray(info))
+    )(kv_ids, q_ids, q_first, q, k, v, do, lse, delta, *info_args)
     return dq, dk, dv
 
 
@@ -247,11 +267,11 @@ def _flash_bwd_call(q, k, v, do, lse, delta, kv_ids, q_ids, q_first, causal,
 # worker-parallel kernel body (grid = (bh, n_workers, max_chain_len))
 # --------------------------------------------------------------------------- #
 def _worker_bwd_kernel(kv_ids, q_ids, valid, q_first,  # (W, T) scalar prefetch
-                       q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       qinfo_ref, kinfo_ref,
-                       dq_hbm, dk_ref, dv_ref,
-                       dq_scratch, sem_in, sem_out,
-                       *, sm_scale, causal, block_q, block_k, mask_spec=None):
+                       q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                       sm_scale, causal, block_q, block_k, mask_spec=None,
+                       has_info=False):
+    q_info, k_info, (dq_hbm, dk_ref, dv_ref, dq_scratch, sem_in, sem_out) = \
+        split_info_refs(refs, has_info)
     b = pl.program_id(0)
     w = pl.program_id(1)
     t = pl.program_id(2)
@@ -266,9 +286,10 @@ def _worker_bwd_kernel(kv_ids, q_ids, valid, q_first,  # (W, T) scalar prefetch
         dq_contrib, dk_contrib, dv_contrib = _task_grads(
             q_ref[0].astype(jnp.float32), k_ref[0].astype(jnp.float32),
             v_ref[0].astype(jnp.float32), do_ref[0].astype(jnp.float32),
-            lse_ref[0], delta_ref[0], kv, qi, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, mask_spec=mask_spec,
-            q_info=qinfo_ref[...], k_info=kinfo_ref[...])
+            _row_to_col(lse_ref[0]), _row_to_col(delta_ref[0]), kv, qi,
+            sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, mask_spec=mask_spec, q_info=q_info,
+            k_info=k_info)
 
         # dK/dV: the worker owns this KV row outright (§3.1), so the block is
         # private to (b, w) and stays VMEM-resident across the row's chain run.
@@ -288,22 +309,8 @@ def _worker_bwd_kernel(kv_ids, q_ids, valid, q_first,  # (W, T) scalar prefetch
         # dQ: accumulate into the worker-PRIVATE fp32 partial (b, w, :, :).
         # No cross-worker ordering is needed — the fixed-order combine kernel
         # realizes the reduction phase (cost r) after the grid completes.
-        dq_slice = dq_hbm.at[b, w, pl.ds(qi * block_q, block_q), :]
-
-        @pl.when(q_first[w, t] == 1)
-        def _fresh():
-            dq_scratch[...] = dq_contrib
-
-        @pl.when(q_first[w, t] == 0)
-        def _rmw():
-            cp_in = pltpu.make_async_copy(dq_slice, dq_scratch, sem_in)
-            cp_in.start()
-            cp_in.wait()
-            dq_scratch[...] += dq_contrib
-
-        cp_out = pltpu.make_async_copy(dq_scratch, dq_slice, sem_out)
-        cp_out.start()
-        cp_out.wait()
+        _dq_rmw(dq_hbm.at[b, w, pl.ds(qi * block_q, block_q), :], dq_scratch,
+                dq_contrib, q_first[w, t] == 1, sem_in, sem_out)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale", "block_q",
@@ -316,13 +323,15 @@ def _flash_bwd_worker_call(q, k, v, do, lse, delta, kv_ids, q_ids, valid,
     sk = k.shape[1]
     n_workers, max_chain = (int(s) for s in kv_ids.shape)
     grid = (bh, n_workers, max_chain)
+    dp = dq_lanes(d)
+    info_specs, info_args = info_inputs(
+        mask, sq, lambda b, w, t, kvi, qi, va, qf: (qi[w, t], 0),
+        lambda b, w, t, kvi, qi, va, qf: (0, kvi[w, t]), block_q, block_k)
     kernel = functools.partial(
         _worker_bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, mask_spec=mask)
+        block_k=block_k, mask_spec=mask, has_info=bool(info_args))
     kvb = functools.partial(kv_head_index, n_heads=n_heads,
                             n_kv_heads=n_kv_heads)
-    info = mask.token_info(sq) if mask is not None else None
-    info = np.zeros((sq,), np.int32) if info is None else info
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -336,15 +345,11 @@ def _flash_bwd_worker_call(q, k, v, do, lse, delta, kv_ids, q_ids, valid,
                          lambda b, w, t, kvi, qi, va, qf: (kvb(b), kvi[w, t], 0)),
             pl.BlockSpec((1, block_q, d),
                          lambda b, w, t, kvi, qi, va, qf: (b, qi[w, t], 0)),
-            pl.BlockSpec((1, block_q),
-                         lambda b, w, t, kvi, qi, va, qf: (b, qi[w, t])),
-            pl.BlockSpec((1, block_q),
-                         lambda b, w, t, kvi, qi, va, qf: (b, qi[w, t])),
-            pl.BlockSpec((block_q,),
-                         lambda b, w, t, kvi, qi, va, qf: (qi[w, t],)),
-            pl.BlockSpec((block_k,),
-                         lambda b, w, t, kvi, qi, va, qf: (kvi[w, t],)),
-        ],
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, w, t, kvi, qi, va, qf: (b, 0, qi[w, t])),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, w, t, kvi, qi, va, qf: (b, 0, qi[w, t])),
+        ] + info_specs,
         out_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # dq partials: explicit DMA RMW
             pl.BlockSpec((1, block_k, d),
@@ -353,7 +358,7 @@ def _flash_bwd_worker_call(q, k, v, do, lse, delta, kv_ids, q_ids, valid,
                          lambda b, w, t, kvi, qi, va, qf: (b, kvi[w, t], 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dp), jnp.float32),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
@@ -362,15 +367,14 @@ def _flash_bwd_worker_call(q, k, v, do, lse, delta, kv_ids, q_ids, valid,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, n_workers, sq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n_workers, sq, dp), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(kv_ids, q_ids, valid, q_first, q, k, v, do, lse, delta,
-      jnp.asarray(info), jnp.asarray(info))
+    )(kv_ids, q_ids, valid, q_first, q, k, v, do, lse, delta, *info_args)
     return dq_part, dk, dv
 
 
@@ -482,8 +486,10 @@ def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
     assert schedule.n_kv == sk // block_k and schedule.n_q == sq // block_q, (
         f"schedule ({schedule.n_kv}x{schedule.n_q}) != tiling "
         f"({sk // block_k}x{sq // block_q})")
-    # D = rowsum(dO ∘ O)  (Alg. 1 line 1 — preprocessing)
+    # D = rowsum(dO ∘ O)  (Alg. 1 line 1 — preprocessing); lse and D enter
+    # the kernels as lane-dense (BH, 1, S) rows
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    lse, delta = lse.reshape(bh, 1, sq), delta.reshape(bh, 1, sq)
 
     if worker_parallel:
         # Non-registry schedules degrade to the serialized realization instead
@@ -502,7 +508,7 @@ def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
             jnp.asarray(wc["valid"]), jnp.asarray(wc["q_first"]),
             causal, sm_scale, block_q, block_k, interpret, n_heads, n_kv_heads,
             mask=mask)
-        dq = fold_combine(dq_part, wc["visited"], block_q, interpret)
+        dq = fold_combine(dq_part, wc["visited"], block_q, interpret)[..., :d]
     else:
         kv_ids, q_ids = serialize_schedule(schedule)
         q_first = first_visit_flags(kv_ids, q_ids)
@@ -510,6 +516,7 @@ def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
             q, k, v, do, lse, delta, jnp.asarray(kv_ids), jnp.asarray(q_ids),
             jnp.asarray(q_first), causal, sm_scale, block_q, block_k,
             interpret, n_heads, n_kv_heads, mask=mask)
+        dq = dq[..., :d]
 
     if mask is not None and schedule.cells is not None:
         # a KV row with no surviving tiles (e.g. keys beyond every sliding

@@ -42,9 +42,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):      # named TPUCompilerParams on jax 0.4.x
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 from repro.kernels.gqa import kv_head_index
 
 NEG_INF = -1e30
@@ -147,11 +144,19 @@ def _fwd_body(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, sm_scale, causal,
     m_ref[...] = m_new
 
 
+def col_to_row(x):
+    """(n, 1) column -> (1, n) row by a 128-lane transpose: Mosaic has no
+    sublane<->lane reshape, but transposes whole (n, 128) f32 tiles."""
+    return jnp.broadcast_to(x, (x.shape[0], 128)).T[:1]
+
+
 def _finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     l = l_ref[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[:, 0]
+    # lse leaves as a lane-dense (1, block_q) row of a (BH, 1, S) array: a
+    # (1, block_q) block of a (BH, S) array breaks the TPU (8, 128) block rule
+    lse_ref[0] = col_to_row(m_ref[...] + jnp.log(l_safe))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -195,15 +200,38 @@ def _fwd_sched_kernel(kv_ids, q_ids, first, last,      # scalar prefetch (SMEM)
         _finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref)
 
 
+def split_info_refs(refs, has_info):
+    """(q_info, k_info, rest) from a kernel's trailing refs: the token_info
+    tile pair (a (block_q, 1) column and a (1, block_k) row) leads ``refs``
+    when the mask spec ships a table, and is absent otherwise."""
+    if not has_info:
+        return None, None, refs
+    return refs[0][...], refs[1][...], refs[2:]
+
+
+def info_inputs(mask, s, q_map, k_map, block_q, block_k):
+    """BlockSpecs + arrays threading ``mask.token_info(s)`` into a kernel:
+    the q side as an (S, 1) column, the k side as a (1, S) row, so each tile
+    is 2-D and meets the TPU (8, 128) block rule. Empty when there is no
+    table (position-only specs never ship one)."""
+    info = None if mask is None else mask.token_info(s)
+    if info is None:
+        return [], []
+    info = jnp.asarray(info, jnp.int32)
+    return ([pl.BlockSpec((block_q, 1), q_map),
+             pl.BlockSpec((1, block_k), k_map)],
+            [info.reshape(s, 1), info.reshape(1, s)])
+
+
 def _fwd_mask_kernel(kv_ids, q_ids, first, last,       # scalar prefetch (SMEM)
-                     q_ref, k_ref, v_ref, qinfo_ref, kinfo_ref,
-                     o_ref, lse_ref,
-                     acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k,
-                     mask_spec):
+                     q_ref, k_ref, v_ref, *refs, sm_scale, block_q, block_k,
+                     mask_spec, has_info):
     """Block-sparse-mask forward: like the causal scheduled kernel but the
     tile predicate comes from the spec, with per-tile slices of the spec's
     token_info table threaded as real inputs (Pallas kernels cannot capture
     array constants)."""
+    q_info, k_info, (o_ref, lse_ref, acc_ref, m_ref, l_ref) = \
+        split_info_refs(refs, has_info)
     t = pl.program_id(1)
     qi = q_ids[t]
     ki = kv_ids[t]
@@ -216,7 +244,7 @@ def _fwd_mask_kernel(kv_ids, q_ids, first, last,       # scalar prefetch (SMEM)
 
     _fwd_body(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, sm_scale=sm_scale,
               causal=False, q_start=qi * block_q, k_start=ki * block_k,
-              mask_spec=mask_spec, q_info=qinfo_ref[...], k_info=kinfo_ref[...])
+              mask_spec=mask_spec, q_info=q_info, k_info=k_info)
 
     @pl.when(last[t] == 1)
     def _fin():
@@ -264,7 +292,7 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
                             n_kv_heads=n_kv_heads)
     out_shape = [
         jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((bh, sq), jnp.float32),
+        jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, d), jnp.float32),   # acc
@@ -272,14 +300,24 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
         pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
     ]
 
-    if mask is not None:
-        kv_ids, q_ids, first, last, _ = mask_grid(mask, n_q, n_k,
-                                                  block_q, block_k)
-        info = mask.token_info(sq)
-        info = np.zeros((sq,), np.int32) if info is None else info
-        kernel = functools.partial(
-            _fwd_mask_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k, mask_spec=mask)
+    if mask is not None or causal:
+        # scheduled grid: only the (q, kv) tiles that attend anything
+        if mask is not None:
+            kv_ids, q_ids, first, last, _ = mask_grid(mask, n_q, n_k,
+                                                      block_q, block_k)
+            info_specs, info_args = info_inputs(
+                mask, sq, lambda b, t, kvi, qi, fi, la: (qi[t], 0),
+                lambda b, t, kvi, qi, fi, la: (0, kvi[t]), block_q, block_k)
+            kernel = functools.partial(
+                _fwd_mask_kernel, sm_scale=sm_scale, block_q=block_q,
+                block_k=block_k, mask_spec=mask, has_info=bool(info_args))
+        else:
+            kv_ids, q_ids, first, last = causal_grid(n_q, n_k, block_q,
+                                                     block_k)
+            info_specs, info_args = [], []
+            kernel = functools.partial(
+                _fwd_sched_kernel, sm_scale=sm_scale, block_q=block_q,
+                block_k=block_k)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(bh, int(kv_ids.shape[0])),
@@ -290,14 +328,12 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
                              lambda b, t, kvi, qi, fi, la: (kvb(b), kvi[t], 0)),
                 pl.BlockSpec((1, block_k, d),
                              lambda b, t, kvi, qi, fi, la: (kvb(b), kvi[t], 0)),
-                pl.BlockSpec((block_q,), lambda b, t, kvi, qi, fi, la: (qi[t],)),
-                pl.BlockSpec((block_k,), lambda b, t, kvi, qi, fi, la: (kvi[t],)),
-            ],
+            ] + info_specs,
             out_specs=[
                 pl.BlockSpec((1, block_q, d),
                              lambda b, t, kvi, qi, fi, la: (b, qi[t], 0)),
-                pl.BlockSpec((1, block_q),
-                             lambda b, t, kvi, qi, fi, la: (b, qi[t])),
+                pl.BlockSpec((1, 1, block_q),
+                             lambda b, t, kvi, qi, fi, la: (b, 0, qi[t])),
             ],
             scratch_shapes=scratch_shapes,
         )
@@ -309,51 +345,15 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(jnp.asarray(kv_ids), jnp.asarray(q_ids), jnp.asarray(first),
-          jnp.asarray(last), q, k, v, jnp.asarray(info), jnp.asarray(info))
-        return out, lse
+          jnp.asarray(last), q, k, v, *info_args)
+        return out, lse.reshape(bh, sq)
 
-    if causal:
-        kv_ids, q_ids, first, last = causal_grid(n_q, n_k, block_q, block_k)
-        kernel = functools.partial(
-            _fwd_sched_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(bh, int(kv_ids.shape[0])),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d),
-                             lambda b, t, kvi, qi, fi, la: (b, qi[t], 0)),
-                pl.BlockSpec((1, block_k, d),
-                             lambda b, t, kvi, qi, fi, la: (kvb(b), kvi[t], 0)),
-                pl.BlockSpec((1, block_k, d),
-                             lambda b, t, kvi, qi, fi, la: (kvb(b), kvi[t], 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d),
-                             lambda b, t, kvi, qi, fi, la: (b, qi[t], 0)),
-                pl.BlockSpec((1, block_q),
-                             lambda b, t, kvi, qi, fi, la: (b, qi[t])),
-            ],
-            scratch_shapes=scratch_shapes,
-        )
-        out, lse = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(jnp.asarray(kv_ids), jnp.asarray(q_ids), jnp.asarray(first),
-          jnp.asarray(last), q, k, v)
-        return out, lse
-
-    grid = (bh, n_q, n_k)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, block_q=block_q,
         block_k=block_k, n_kv_tiles=n_k)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (kvb(b), ki, 0)),
@@ -361,7 +361,7 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
@@ -369,4 +369,4 @@ def flash_fwd(q, k, v, causal=False, sm_scale=None, block_q=128, block_k=128,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse.reshape(bh, sq)

@@ -9,7 +9,9 @@ the spec hash.  ``attention(..., impl=...)`` is the model-facing dispatcher:
   impl="xla"     — reference jnp attention (used by model code on CPU, in smoke
                    tests and in the multi-pod dry-run, where a custom kernel would
                    obscure cost_analysis and explode CPU compile times);
-  impl="pallas"  — the DASH kernels (TARGET: TPU; validated via interpret=True).
+  impl="pallas"  — the DASH kernels: tested in interpret mode on CPU, and run
+                   on a TPU v5e chip against the f32 reference and through
+                   the train step (chip_smoke.py).
 
 Public shapes are (batch, heads, seq, head_dim). GQA is **native** on both
 paths: K/V keep their (batch, kv_heads, seq, head_dim) shape end to end — no
@@ -114,11 +116,11 @@ def dash_attention(q, k, v, causal: bool = False,
       block: square tile size (MXU-aligned; 128 default).
       tune: ``True``/"sim" lets :func:`repro.tune.tune_attention` resolve
         (schedule, block, worker_parallel) from the modeled makespan for this
-        (shape, dtype, mask) key; "measure" additionally times the top
-        candidates (needs a tuner cache populated by a measured run — falls
-        back to sim ranking otherwise).  Tuning only *selects* knobs: the
-        tuned call is bitwise identical to the hand-configured call with the
-        same resolved (schedule, block, worker_parallel).
+        (shape, dtype, mask) key; "measure" takes the winner of a measured
+        run from the tuner cache and raises when there is none.  Tuning only
+        *selects* knobs: the tuned call is bitwise identical to the
+        hand-configured call with the same resolved (schedule, block,
+        worker_parallel).
       worker_parallel: realize the backward across schedule worker chains
         (bitwise-equal to serialized when the schedule is single-visit;
         auto-degrades otherwise).  Overridden by ``tune``.
@@ -326,15 +328,19 @@ def attention(q, k, v, causal: bool = False, impl: str = "xla",
     carry ``n_kv_heads`` — the former must be a multiple of the latter.
 
     ``mask`` (static MaskSpec) reaches both impls; ``segment_ids`` (dynamic
-    per-row packing) has no static block map, so it always runs the xla path —
+    per-row packing) has no static block map, so only the xla impl takes it —
     static packing layouts that should hit the Pallas grid go through
     ``mask=Document(...)`` instead.
     """
     validate_group(q.shape[1], k.shape[1])
-    if impl == "xla" or segment_ids is not None:
+    if impl == "xla":
         return xla_attention(q, k, v, causal, sm_scale, chunk_q=chunk_q,
                              mask=mask, segment_ids=segment_ids)
     if impl == "pallas":
+        if segment_ids is not None:
+            raise ValueError(
+                "impl='pallas' takes no dynamic segment_ids: pass a static "
+                "mask=Document(...) or use impl='xla'")
         return dash_attention(q, k, v, causal, schedule, sm_scale,
                               interpret=interpret, mask=mask, tune=tune)
     raise ValueError(f"unknown attention impl {impl!r}")

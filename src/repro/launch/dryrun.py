@@ -175,8 +175,6 @@ def _lower_compile(cfg, shape, rules, tcfg, mesh):
 
 def _measures(compiled, n_dev):
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jaxlib<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     coll, counts = collective_bytes(compiled.as_text(), n_dev)
     return {"flops": cost.get("flops", 0.0),
             "bytes_accessed": cost.get("bytes accessed", 0.0),

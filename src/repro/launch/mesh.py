@@ -25,13 +25,21 @@ mesh axis.  Three deployments, in increasing intrusiveness:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes. The sharding code places arrays with
+    ``with_sharding_constraint`` and ``shard_map`` under a mesh it does not
+    type, which the Explicit axes ``jax.make_mesh`` makes by default refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per v5e pod; the multi-pod mesh stacks 2 pods (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_cp_mesh(n_data: int = 16, n_cp: int = 2, n_model: int = 8):
@@ -42,11 +50,11 @@ def make_cp_mesh(n_data: int = 16, n_cp: int = 2, n_model: int = 8):
     permutes over; ``RULE_SETS["cp"]``-style rules should map ``seq → cp`` and
     keep TP rules on ``model``.
     """
-    return jax.make_mesh((n_data, n_cp, n_model), ("data", "cp", "model"))
+    return auto_mesh((n_data, n_cp, n_model), ("data", "cp", "model"))
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, multi_pod: bool = False):
     """Small mesh for in-test lowering on forced-multi-device CPU."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))

@@ -33,6 +33,7 @@ import numpy as np
 from repro.configs import registry
 from repro.launch.specs import make_batch
 from repro.configs.base import InputShape
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import ContinuousEngine, SampleConfig
 
@@ -227,6 +228,7 @@ def main(argv=None):
         ap.error("--spec-k must be >= 0")
     if (args.track or args.trace_out) and args.engine != "continuous":
         ap.error("--track/--trace-out apply to --engine continuous")
+    use_compile_cache()
 
     cfg = registry.get(args.arch)
     if args.reduced:

@@ -4,9 +4,10 @@
         --steps 200 --batch 8 --seq 256 [--resume] [--ckpt-dir DIR]
 
 Runs a real training loop (synthetic or memmap data) with periodic async
-checkpointing and exact resume (stateless data sampler + full optimizer state).
-On CPU this trains the reduced configs (~100M-class models at --reduced-large);
-on a real pod the same code path jits under the production mesh via --mesh.
+checkpointing and exact resume (stateless data sampler + full optimizer state)
+on one device. On CPU this trains the reduced configs (~100M-class models at
+--reduced-large); ``chip_smoke.py`` drives the same functions at published
+widths on a TPU.
 """
 from __future__ import annotations
 
@@ -21,13 +22,13 @@ import jax.numpy as jnp
 from repro.ckpt import checkpoint as C
 from repro.configs import registry
 from repro.data.pipeline import DataConfig, make_source
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import optimizer as O
 from repro.train import step as S
 
 
 def build(cfg, tcfg):
-    step_fn = jax.jit(S.make_train_step(cfg, tcfg), donate_argnums=(0,))
-    return step_fn
+    return S.jit(cfg, S.make_train_step(cfg, tcfg), donate_argnums=(0,))
 
 
 def main(argv=None):
@@ -63,12 +64,10 @@ def main(argv=None):
                          "<ckpt-dir>/digest_chain.json or ./digest_chain.json)")
     ap.add_argument("--heartbeat", action="store_true",
                     help="enable straggler/hang monitor (launch/heartbeat.py)")
-    ap.add_argument("--tune", default="off", choices=["off", "sim", "measure"],
+    ap.add_argument("--tune", default="off", choices=["off", "sim"],
                     help="resolve the attention schedule knobs with "
                          "repro.tune before training: 'sim' ranks by modeled "
-                         "makespan (pure, reproducible); 'measure' also times "
-                         "the top candidates when a runner/cache is available "
-                         "(falls back to sim ranking here). The choice is "
+                         "makespan (pure, reproducible). The choice is "
                          "logged and feeds the utilization-vs-modeled metric.")
     ap.add_argument("--track", default=None, metavar="JSONL",
                     help="write a repro.obs event stream here: per-step "
@@ -93,6 +92,7 @@ def main(argv=None):
                          "run's loss/digests are unchanged (README "
                          "§Robustness)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = registry.get(args.arch)
     if args.reduced_large:

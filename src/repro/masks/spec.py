@@ -77,8 +77,8 @@ class MaskSpec:
         """In-kernel mask evaluation on one tile.
 
         ``rows``/``cols`` are (bq, bk) absolute-position iotas; ``q_info`` /
-        ``k_info`` are the (bq,) / (bk,) slices of :meth:`token_info` for the
-        tile (ignored by position-only specs). Must agree with
+        ``k_info`` are the tile's slices of :meth:`token_info` as a (bq, 1)
+        column and a (1, bk) row (ignored by position-only specs). Must agree with
         :meth:`mask_fn` — the property tests compare the kernels driven by
         this method against the :meth:`materialize` oracle."""
         return self.mask_fn(rows, cols)
@@ -215,7 +215,7 @@ class Document(MaskSpec):
         return np.asarray(self.segment_ids, np.int32)
 
     def tile_mask(self, rows, cols, q_info=None, k_info=None):
-        same = q_info[:, None] == k_info[None, :]
+        same = q_info == k_info
         return same & (rows >= cols) if self.causal else same
 
     def materialize(self, sq: int, sk: int = None) -> np.ndarray:

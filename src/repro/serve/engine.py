@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist import fold
 from repro.models import transformer as T
 from repro.serve.kv_cache import PagedKVCache, PagedLayout
 from repro.serve.scheduler import FCFSScheduler, Request
@@ -224,8 +225,10 @@ class Engine:
 @functools.lru_cache(maxsize=None)
 def _paged_step_fn(cfg):
     """Shared jitted paged step — cached per (hashable, frozen) config so many
-    engine instances (the invariance suite builds dozens) reuse compilations."""
-    return jax.jit(functools.partial(T.paged_step, cfg=cfg))
+    engine instances (the invariance suite builds dozens) reuse compilations.
+    Compiled like the sharded step (``fold.exact_jit``), so the two round
+    alike."""
+    return fold.exact_jit(functools.partial(T.paged_step, cfg=cfg))
 
 
 @functools.lru_cache(maxsize=None)
@@ -353,8 +356,11 @@ class ContinuousEngine:
         if mesh is None:
             self._step = _paged_step_fn(cfg)
         else:
-            from repro.serve.sharded import make_sharded_paged_step
-            sharded = make_sharded_paged_step(cfg, mesh, params,
+            from repro.serve.sharded import (make_sharded_paged_step,
+                                             place_on_mesh)
+            self.params, self.cache.pools = place_on_mesh(
+                cfg, mesh, params, self.cache.pools)
+            sharded = make_sharded_paged_step(cfg, mesh, self.params,
                                               self.cache.pools,
                                               prof=self.prof)
             dev = mesh.devices.flat[0]

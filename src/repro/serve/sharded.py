@@ -27,8 +27,7 @@ import functools
 import json
 
 import jax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import fold
 from repro.models import transformer as T
@@ -111,10 +110,27 @@ def _builder_cache(cfg, mesh):
                     P(None, None), P(None, None), P(None, None),
                     P(None), P(None))
         out_specs = (logits_spec, _pool_specs(cfg, caches, tp))
-        return jax.jit(shard_map(step, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return fold.exact_jit(jax.shard_map(step, mesh=mesh,
+                                            in_specs=in_specs,
+                                            out_specs=out_specs,
+                                            check_vma=False))
 
     return make
+
+
+def place_on_mesh(cfg, mesh, params, caches):
+    """``(params, caches)`` moved onto ``mesh`` with the sharded step's own
+    in_specs. Done once: a step fed arrays that live on one device reshards
+    the whole model on every call, and that device keeps a full copy."""
+    tp = int(mesh.shape[AXIS])
+
+    def put(tree, specs):
+        return jax.device_put(tree, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P)))
+
+    return (put(params, _param_specs(cfg, params, tp)),
+            put(caches, _pool_specs(cfg, caches, tp)))
 
 
 def make_sharded_paged_step(cfg, mesh, params, caches, prof=None):

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist import fold
 from repro.models import transformer as T
 
 
@@ -102,7 +103,7 @@ def _spec_scan_fn(cfg, scfg, n_steps: int, teacher_forced: bool):
             (jnp.arange(n_steps), feed, pos, wp, wo))
         return toks.T, lps.T, pools
 
-    return jax.jit(run)
+    return fold.exact_jit(run)
 
 
 class Speculator:
@@ -135,8 +136,6 @@ class Speculator:
             lay = eng.cache.layout
             self.pools = T.init_paged_cache(self.dcfg, lay.n_pages + 1,
                                             lay.page_size)
-            self._dstep = None if eng.mesh is None else jax.jit(
-                functools.partial(T.paged_step, cfg=self.dcfg))
         else:
             self.pools = None           # alias: target pools are the drafter's
         # telemetry: drafted counts proposals, accepted counts verified
@@ -164,7 +163,10 @@ class Speculator:
         identical to never having been preempted."""
         if self.self_draft:
             return
-        step = self._dstep or _paged_step_for(self.dcfg)
+        # the drafter always runs single-device, whether or not the engine's
+        # own step is mesh-sharded
+        from repro.serve.engine import _paged_step_fn
+        step = _paged_step_fn(self.dcfg)
         plen, C = len(tokens), eng.prefill_chunk
         table = eng.cache.device_page_table([slot])
         for start in range(0, plen, C):
@@ -318,10 +320,3 @@ class Speculator:
             if feed is None:
                 cur = jnp.asarray(toks[:, l : l + 1])
         return toks, lps, pools
-
-
-@functools.lru_cache(maxsize=None)
-def _paged_step_for(cfg):
-    """Single-device jitted paged step for a drafter config (the engine's own
-    step may be mesh-sharded; the drafter always runs single-device)."""
-    return jax.jit(functools.partial(T.paged_step, cfg=cfg))

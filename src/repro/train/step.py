@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist import compression
+from repro.dist import compression, fold
 from repro.dist.sharding import logical_to_spec, spec_tree_to_pspecs
 from repro.models import transformer as T
 from repro.train import optimizer as O
@@ -89,8 +89,23 @@ def batch_pspecs(cfg: ModelConfig, rules):
     return out
 
 
+def jit(cfg: ModelConfig, fun, **kw):
+    """Compile ``fun`` the way the trainer compiles its step for ``cfg``.
+
+    In serve-canonical mode (``cfg.canonical_reductions``) the train forward
+    is held bitwise to the serving engine's prefill, so it is compiled like
+    the engine's steps, with ``fold.exact_jit``: every bf16 value is rounded
+    where the program says, not where a fusion happens to. Otherwise plain
+    ``jax.jit``.
+    """
+    if cfg.canonical_reductions:
+        return fold.exact_jit(fun, **kw)
+    return jax.jit(fun, **kw)
+
+
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
-    """Returns step(state, batch) → (state, metrics). Pure; jit outside."""
+    """Returns step(state, batch) → (state, metrics). Pure; jit outside
+    (``jit`` above)."""
 
     def loss_fn(params, batch):
         return T.loss_fn(params, batch, cfg, remat=tcfg.remat,

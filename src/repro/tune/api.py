@@ -83,8 +83,9 @@ def tune_attention(*, seq: int, seq_kv: Optional[int] = None, head_dim: int,
     """Resolve the best legal (schedule, block, realization) for one geometry.
 
     ``mode="sim"`` ranks by modeled makespan only (pure, no hardware);
-    ``mode="measure"`` times the top-``topk`` with ``runner(candidate)``
-    (required for real hardware timing) and persists the winner. Either way
+    ``mode="measure"`` times the top-``topk`` with ``runner(candidate)`` and
+    persists the winner; without a runner it can only answer from the cache,
+    and a cache miss raises ``ValueError``. Either way
     the decision lands in ``cache`` (default: the process-wide store), so the
     next call with the same key is a hit and tuning is idempotent.
     """
@@ -111,6 +112,10 @@ def tune_attention(*, seq: int, seq_kv: Optional[int] = None, head_dim: int,
         _emit_choice(tracker, result, mode, n_candidates=0)
         return result
 
+    if mode == "measure" and runner is None:
+        raise ValueError(
+            f"tune mode 'measure' needs a runner that times candidates on the "
+            f"device, and the cache has no measured record for {key}")
     cands = enumerate_candidates(seq_q=seq, seq_kv=seq_kv, head_dim=head_dim,
                                  dtype_bytes=_dtype_bytes(dtype),
                                  causal=causal, mask=mask,
@@ -118,7 +123,7 @@ def tune_attention(*, seq: int, seq_kv: Optional[int] = None, head_dim: int,
     ranked = rank_candidates(cands, seq_q=seq, seq_kv=seq_kv,
                              head_dim=head_dim, causal=causal, mask=mask)
     source, measured_s = "sim", None
-    if mode == "measure" and runner is not None and len(ranked) > 1:
+    if mode == "measure" and len(ranked) > 1:
         ranked = measure_mod.measure_topk(ranked, runner, k=topk)
         source, measured_s = "measure", ranked[0]["measured_s"]
     win = ranked[0]

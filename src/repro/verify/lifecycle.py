@@ -254,6 +254,7 @@ def run_train_serve_parity(archs=PARITY_ARCHS,
     """
     from repro.models import transformer as T
     from repro.serve.engine import ContinuousEngine
+    from repro.train import step as S
     from repro.verify.digest import combine_leaf_digests, leaf_digest
 
     prompt_lens = (5, 13, 32, 7)
@@ -272,7 +273,7 @@ def run_train_serve_parity(archs=PARITY_ARCHS,
             eng.submit(p, req_id=i, max_new_tokens=1)
         eng.run()
         pcfg = cfg.replace(canonical_reductions=page_size)
-        fwd = jax.jit(lambda pr, b, _c=pcfg: T.forward(pr, b, _c)[0])
+        fwd = S.jit(pcfg, lambda pr, b, _c=pcfg: T.forward(pr, b, _c)[0])
         train_d, serve_d = {}, {}
         for i, p in enumerate(prompts):
             toks = jnp.asarray(np.asarray(p, np.int32)[None])

@@ -36,6 +36,7 @@ import dataclasses
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence
 
 import jax
+from jax.extend import core as jex_core
 
 UNORDERED_SCATTERS = frozenset(
     {"scatter", "scatter-add", "scatter-mul", "scatter-min", "scatter-max"})
@@ -59,15 +60,15 @@ def _subjaxprs(params: Dict[str, Any]):
     for v in params.values():
         items = v if isinstance(v, (list, tuple)) else (v,)
         for item in items:
-            if isinstance(item, jax.core.Jaxpr):
+            if isinstance(item, jex_core.Jaxpr):
                 yield item
-            elif isinstance(item, jax.core.ClosedJaxpr):
+            elif isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
 
 
 _LOOK_THROUGH = frozenset({"convert_element_type", "reshape", "squeeze",
                            "broadcast_in_dim", "copy"})
-_CALL_LIKE = frozenset({"pjit", "closed_call", "core_call", "custom_jvp_call",
+_CALL_LIKE = frozenset({"jit", "closed_call", "core_call", "custom_jvp_call",
                         "custom_vjp_call", "remat2", "checkpoint"})
 _COMPARISONS = frozenset({"eq", "ne", "lt", "le", "gt", "ge"})
 
@@ -144,7 +145,7 @@ def audit_jaxpr(jaxpr, *, allow: Sequence[str] = ()) -> List[Finding]:
     ``allow`` suppresses finding codes by name (e.g. a job that accepts
     topology-dependent gradient bits may allow ``unordered-psum``).
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     allow_set: FrozenSet[str] = frozenset(allow)
     findings: List[Finding] = []
@@ -195,7 +196,13 @@ def audit_jaxpr(jaxpr, *, allow: Sequence[str] = ()) -> List[Finding]:
 
 
 def audit_fn(fn, *args, allow: Sequence[str] = (), **kwargs) -> List[Finding]:
-    """Trace ``fn(*args, **kwargs)`` and audit the resulting jaxpr."""
+    """Trace ``fn(*args, **kwargs)`` and audit the resulting jaxpr.
+
+    A jitted ``fn`` is traced through its own ``trace``: wrapped in
+    ``make_jaxpr`` it would be a nested jit, and a jit that carries
+    ``compiler_options`` (``fold.exact_jit``) may only be top-level."""
+    if hasattr(fn, "trace"):
+        return audit_jaxpr(fn.trace(*args, **kwargs).jaxpr, allow=allow)
     return audit_jaxpr(jax.make_jaxpr(fn)(*args, **kwargs), allow=allow)
 
 

@@ -10,8 +10,7 @@ query row's output must be
   * **bitwise** invariant to page-table permutations (physical placement),
     trailing unallocated pages, and the content of other batch rows.
 
-Property tests go through ``hypothesis`` (the deterministic stub in
-``repro._compat`` when the real package is absent — see conftest.py).
+Property tests go through ``hypothesis``.
 """
 import numpy as np
 import pytest
@@ -72,7 +71,7 @@ def ref_rows(q, k, v, lens):
     return np.stack(outs)                                            # (B,1,H,D)
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(seed=st.integers(0, 10_000), page_size=st.sampled_from([4, 8, 16]),
        gqa=st.booleans())
 def test_decode_matches_ref(seed, page_size, gqa):
@@ -86,7 +85,7 @@ def test_decode_matches_ref(seed, page_size, gqa):
                                rtol=2e-5, atol=2e-5)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(seed=st.integers(0, 10_000), chunk=st.sampled_from([1, 3, 8]))
 def test_prefill_rows_match_ref(seed, chunk):
     """Multi-query (chunked-prefill) rows: query at position p attends [0, p]."""

@@ -138,7 +138,6 @@ _RING_FOLD_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import determinism as det
 
@@ -146,9 +145,9 @@ _RING_FOLD_SCRIPT = textwrap.dedent("""
                            maxval=1e4)
     for n in (2, 4, 8):
         mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
-        f = jax.jit(shard_map(lambda v: det.ring_ordered_psum(v[0], "x"),
-                              mesh=mesh, in_specs=(P("x"),),
-                              out_specs=P(None), check_rep=False))
+        f = jax.jit(jax.shard_map(lambda v: det.ring_ordered_psum(v[0], "x"),
+                                  mesh=mesh, in_specs=(P("x"),),
+                                  out_specs=P(None), check_vma=False))
         got = f(x[:n])
         # sequential left fold over the n shards — the declared association
         want = det.ordered_sum(x[:n])
@@ -173,11 +172,10 @@ def test_ring_ordered_psum_single_device():
     """Association check on a 1D mesh of size 1 (CPU) — full multi-device variant
     is exercised in test_dist_collectives.py under a forced 8-device platform."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
     x = jnp.arange(4, dtype=jnp.float32)
-    f = shard_map(lambda v: det.ring_ordered_psum(v, "x"), mesh=mesh,
-                  in_specs=(jax.sharding.PartitionSpec("x"),),
-                  out_specs=jax.sharding.PartitionSpec())
+    f = jax.shard_map(lambda v: det.ring_ordered_psum(v, "x"), mesh=mesh,
+                      in_specs=(jax.sharding.PartitionSpec("x"),),
+                      out_specs=jax.sharding.PartitionSpec())
     # n=1: identity
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))

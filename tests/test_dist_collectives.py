@@ -28,17 +28,17 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import auto_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import determinism as det
 
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = auto_mesh((8,), ("x",))
     x = jax.random.uniform(jax.random.PRNGKey(0), (8, 64), minval=-1e4,
                            maxval=1e4)
 
-    f = jax.jit(shard_map(lambda v: det.ring_ordered_psum(v[0], "x"),
-                          mesh=mesh, in_specs=(P("x"),), out_specs=P(None),
-                          check_rep=False))
+    f = jax.jit(jax.shard_map(lambda v: det.ring_ordered_psum(v[0], "x"),
+                              mesh=mesh, in_specs=(P("x"),), out_specs=P(None),
+                              check_vma=False))
     got = f(x)
     # association pinned to ascending device index == strict left-to-right fold
     want = det.ordered_sum(x)
@@ -120,7 +120,7 @@ FOLD_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import auto_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import determinism as det
     from repro.dist import fold
@@ -135,10 +135,10 @@ FOLD_SCRIPT = textwrap.dedent("""
             want, np.asarray(det.ordered_sum(x.astype(jnp.float32))
                              if dtype == jnp.float32 else want))
         for n in (1, 2, 4, 8):
-            mesh = jax.make_mesh((n,), ("m",))
-            f = jax.jit(shard_map(
+            mesh = auto_mesh((n,), ("m",))
+            f = jax.jit(jax.shard_map(
                 lambda v: fold.fixed_fold_psum(v, "m"), mesh=mesh,
-                in_specs=(P("m"),), out_specs=P(None), check_rep=False))
+                in_specs=(P("m"),), out_specs=P(None), check_vma=False))
             got = np.asarray(f(x))
             assert np.array_equal(got, want), (str(dtype), n)
         print(f"fixed_fold_psum invariant over n in (1,2,4,8) {dtype.__name__}")
@@ -146,11 +146,11 @@ FOLD_SCRIPT = textwrap.dedent("""
     # the fold's collectives pass the nondeterminism auditor: the ppermute
     # ring moves data only and the final psum is the blessed one-hot
     # axis_index broadcast
-    mesh = jax.make_mesh((4,), ("m",))
+    mesh = auto_mesh((4,), ("m",))
     x = jax.random.uniform(jax.random.PRNGKey(1), (8, 4, 64))
-    f = jax.jit(shard_map(lambda v: fold.fixed_fold_psum(v, "m"), mesh=mesh,
-                          in_specs=(P("m"),), out_specs=P(None),
-                          check_rep=False))
+    f = jax.jit(jax.shard_map(lambda v: fold.fixed_fold_psum(v, "m"), mesh=mesh,
+                              in_specs=(P("m"),), out_specs=P(None),
+                              check_vma=False))
     findings = trace.audit_fn(f, x)
     assert findings == [], findings
     print("fixed_fold_psum trace audit clean")
@@ -170,7 +170,7 @@ def test_fixed_fold_psum_topology_invariant():
     assert "fixed_fold_psum trace audit clean" in r.stdout
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(v=st.sampled_from([1, 2, 4, 8]), rows=st.integers(1, 6),
        cols=st.sampled_from([1, 3, 32]), bf16=st.booleans(),
        seed=st.integers(0, 2 ** 16))
@@ -191,7 +191,7 @@ def test_fixed_fold_matches_sequential_fold(v, rows, cols, bf16, seed):
     np.testing.assert_array_equal(got, np.asarray(acc))
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(width=st.sampled_from([16, 32, 64]), bf16=st.booleans(),
        seed=st.integers(0, 2 ** 16))
 def test_canonical_row_dot_matches_folded_partials(width, bf16, seed):

@@ -19,12 +19,13 @@ SCRIPT = textwrap.dedent("""
     from repro.dist.sharding import RULE_SETS, use_rules, logical_to_spec, \\
         sanitize_pspecs
     from repro.launch.dryrun import _measures, collective_bytes
+    from repro.launch.mesh import auto_mesh
     from repro.launch.specs import input_specs
     from repro.models import transformer as T
     from repro.train import step as S
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     # rules reference only data/model axes on this mesh
     rules = {k: (tuple(a for a in v if a in ("data", "model")) or None)
              if v else v for k, v in RULE_SETS["fsdp_tp"](False).items()}

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 
 from repro.core.schedules import cached_schedule, make_schedule
 from repro.kernels import ref
@@ -63,16 +64,16 @@ def _collect_pallas_eqns(jaxpr, acc):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             acc.append(eqn)
-        for val in jax.util.unzip2(eqn.params.items())[1]:
+        for val in eqn.params.values():
             for sub in _subjaxprs(val):
                 _collect_pallas_eqns(sub, acc)
     return acc
 
 
 def _subjaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex_core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for item in val:
@@ -117,7 +118,7 @@ def test_gqa_kernels_allocate_no_repeated_kv(causal):
 def _all_eqns(jaxpr, acc):
     for eqn in jaxpr.eqns:
         acc.append(eqn)
-        for val in jax.util.unzip2(eqn.params.items())[1]:
+        for val in eqn.params.values():
             for sub in _subjaxprs(val):
                 _all_eqns(sub, acc)
     return acc

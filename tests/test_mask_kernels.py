@@ -241,6 +241,16 @@ def test_schedule_mask_mismatch_rejected():
                   interpret=True, mask=b)
 
 
+def test_pallas_impl_refuses_dynamic_segment_ids():
+    """Dynamic segment ids have no static block map: the pallas impl refuses
+    them rather than quietly running the xla path."""
+    q = _rand((1, 2, S, D), jnp.float32, 0)
+    seg = jnp.ones((1, S), jnp.int32)
+    with pytest.raises(ValueError, match="segment_ids"):
+        attention(q, q, q, causal=True, impl="pallas", interpret=True,
+                  segment_ids=seg)
+
+
 # ----------------------------------------------------------- verify.trace
 def test_masked_attention_lowering_audit_clean():
     """The lowered masked forward+backward contains no nondeterminism-prone

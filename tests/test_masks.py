@@ -1,5 +1,5 @@
 """Mask-spec layer: materialize ↔ block-map ↔ tile_mask consistency, algebra,
-hashability/cache-key identity (hypothesis-stub compatible property tests)."""
+hashability/cache-key identity (hypothesis property tests)."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -60,8 +60,8 @@ def test_tile_mask_agrees_with_mask_fn(s, ):
                 rows = q0 + np.arange(b)[:, None] + np.zeros((1, b), np.int64)
                 cols = k0 + np.arange(b)[None, :] + np.zeros((b, 1), np.int64)
                 got = np.asarray(spec.tile_mask(rows, cols,
-                                                info[q0:q0 + b],
-                                                info[k0:k0 + b]), bool)
+                                                info[q0:q0 + b, None],
+                                                info[None, k0:k0 + b]), bool)
                 np.testing.assert_array_equal(
                     got, dense[q0:q0 + b, k0:k0 + b], err_msg=repr(spec))
 

@@ -144,7 +144,7 @@ def test_jsonl_tracker_flushes_every_event(tmp_path):
 
 
 # ------------------------------------------------------------ exact quantiles
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000),
        n=st.integers(min_value=1, max_value=200),
        qi=st.integers(min_value=0, max_value=100))

@@ -14,9 +14,10 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import auto_mesh
     from repro.dist.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = auto_mesh((4,), ("stage",))
     S, B, D = 4, 8, 32
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (S, D, D)) * 0.3

@@ -10,12 +10,13 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import auto_mesh
     from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
     from repro.dist.ring_attention import (ring_attention, zigzag_permutation,
                                            zigzag_inverse)
     from repro.kernels.ops import xla_attention
 
-    mesh = jax.make_mesh((8,), ("cp",))
+    mesh = auto_mesh((8,), ("cp",))
     B, S, H, D = 2, 512, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v = (jax.random.normal(ks[i], (B, S, H, D), jnp.float32) for i in range(3))

@@ -19,8 +19,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import registry
+from repro.dist import fold
+from repro.launch.train import build
 from repro.models import transformer as T
 from repro.serve.engine import ContinuousEngine
+from repro.train import step as S
 from repro.verify import lifecycle as L
 
 PAGE = 8
@@ -44,8 +47,10 @@ def _serve_prefill(cfg, params, prompts):
 
 
 def _train_fwd(cfg):
+    """The canonical train forward, compiled as the trainer compiles its
+    step (``train.step.jit``)."""
     pcfg = cfg.replace(canonical_reductions=PAGE)
-    return jax.jit(lambda pr, b: T.forward(pr, b, pcfg)[0])
+    return S.jit(pcfg, lambda pr, b: T.forward(pr, b, pcfg)[0])
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -99,7 +104,7 @@ def test_packed_parity():
     segs = np.concatenate(
         [np.full(len(d), j + 1) for j, d in enumerate(docs)]
     ).astype(np.int32)[None]
-    packed = np.asarray(jax.jit(lambda pr, b: T.forward(pr, b, pk)[0])(
+    packed = np.asarray(S.jit(pk, lambda pr, b: T.forward(pr, b, pk)[0])(
         params, {"tokens": jnp.asarray(toks), "positions": jnp.asarray(poss),
                  "segment_ids": jnp.asarray(segs)}))[0]
     eng = _serve_prefill(cfg, params, docs)
@@ -150,6 +155,21 @@ def test_canonical_mode_off_by_default():
             params, {"tokens": toks}))
     canon = np.asarray(_train_fwd(cfg)(params, {"tokens": toks}))
     np.testing.assert_array_equal(np.argmax(fused, -1), np.argmax(canon, -1))
+
+
+def test_trainer_compiles_canonical_mode_like_the_engine(monkeypatch):
+    """The forward these tests compare is compiled by the trainer's own
+    policy: ``build`` goes through ``fold.exact_jit``, as the engine's paged
+    step does, exactly when the config is serve-canonical."""
+    seen = []
+    real = fold.exact_jit
+    monkeypatch.setattr(fold, "exact_jit",
+                        lambda f, **kw: seen.append(kw) or real(f, **kw))
+    cfg = registry.get("stablelm-1.6b").reduced()
+    build(cfg, S.TrainConfig())
+    assert seen == []
+    build(cfg.replace(canonical_reductions=PAGE), S.TrainConfig())
+    assert seen == [{"donate_argnums": (0,)}]
 
 
 def test_lifecycle_parity_cell_conformant():

@@ -177,6 +177,17 @@ def test_tune_attention_key_separates_geometries(tmp_path):
     assert b.candidate.schedule == "shift"
 
 
+def test_measure_without_runner_refuses_on_cache_miss(tmp_path):
+    """mode="measure" with no runner never ranks by simulation in its place:
+    a cache miss raises, and nothing is written to the cache."""
+    cache = TuneCache(root=str(tmp_path))
+    with pytest.raises(ValueError, match="runner"):
+        tune_attention(seq=1024, head_dim=128, causal=True, cache=cache,
+                       mode="measure")
+    sim = tune_attention(seq=1024, head_dim=128, causal=True, cache=cache)
+    assert sim.source == "sim"
+
+
 def test_tune_attention_normalizes_paper_masks(tmp_path):
     """Full()/Causal() specs share keys (and decisions) with the flag form."""
     from repro.masks import Causal, Full

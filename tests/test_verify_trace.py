@@ -56,11 +56,10 @@ def test_scatter_inside_scan_is_found():
 
 # -------------------------------------------------------------------- psum
 def _shard1(fn):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    return shard_map(fn, mesh=mesh, in_specs=(P("x"),), out_specs=P(None),
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P("x"),), out_specs=P(None),
+                         check_vma=False)
 
 
 def test_flags_plain_psum_blesses_ring_ordered():
