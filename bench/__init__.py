@@ -1,0 +1,5 @@
+"""The chip benchmark: harness, trace reduction, references and cells.
+
+Run it as ``python3 bench/run.py --workload <cell> --seed <n> --seconds <s>
+--trace <0|1>``; see ``bench/run.py``.
+"""
