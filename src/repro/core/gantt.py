@@ -8,8 +8,8 @@ a glance at the chart shows where masking cost lives. :func:`render_block_map`
 draws the mask's tile classification itself.
 
 :mod:`repro.obs.export` generalizes this picture to a *loadable* artifact:
-the same per-worker lanes as Chrome-trace/Perfetto JSON, with a modeled lane
-(these simulator costs) next to an achieved lane (measured kernel wall time).
+the same per-worker lanes as Chrome-trace/Perfetto JSON, at these simulator
+costs.
 """
 from __future__ import annotations
 

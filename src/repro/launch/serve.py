@@ -164,8 +164,7 @@ def _continuous(cfg, params, args):
     if args.trace_out is not None:
         from repro.obs import export as EX
         events = EX.spans_to_trace(trace_mem.events, process_name=run_id)
-        events += EX.attention_timeline(max_seq, cfg.head_dim, causal=True,
-                                        measure=True)
+        events += EX.attention_timeline(max_seq, cfg.head_dim, causal=True)
         EX.write_trace(args.trace_out, events)
         print(f"[trace] {len(events)} events -> {args.trace_out}", flush=True)
     tracker.close()
@@ -213,9 +212,11 @@ def main(argv=None):
                          "tracking (tests/test_obs_prof.py)")
     ap.add_argument("--trace-out", default=None, metavar="TRACE.json",
                     help="write a Perfetto/Chrome-trace JSON: request/queue/"
-                         "prefill/decode spans plus the attention schedule "
-                         "timeline with modeled and achieved lanes "
-                         "(repro.obs.export); works with or without --track")
+                         "prefill/decode spans plus the attention schedule's "
+                         "modeled lanes (repro.obs.export); works with or "
+                         "without --track. Device time comes from a profiler "
+                         "trace, in which the same spans appear as "
+                         "repro.<phase>")
     args = ap.parse_args(argv)
 
     if (args.tp > 1 or args.mesh) and args.engine != "continuous":

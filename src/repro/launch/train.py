@@ -68,11 +68,11 @@ def main(argv=None):
                     help="resolve the attention schedule knobs with "
                          "repro.tune before training: 'sim' ranks by modeled "
                          "makespan (pure, reproducible). The choice is "
-                         "logged and feeds the utilization-vs-modeled metric.")
+                         "logged.")
     ap.add_argument("--track", default=None, metavar="JSONL",
                     help="write a repro.obs event stream here: per-step "
-                         "throughput, utilization-vs-modeled, fingerprint + "
-                         "divergence events (with --verify), tuner decisions")
+                         "throughput, fingerprint + divergence events (with "
+                         "--verify), tuner decisions")
     ap.add_argument("--track-reference", default=None, metavar="JSONL",
                     help="a previous run's --track file; with --verify, the "
                          "live fingerprint stream is compared against it and "
@@ -81,9 +81,10 @@ def main(argv=None):
     ap.add_argument("--trace-out", default=None, metavar="TRACE.json",
                     help="write a Perfetto/Chrome-trace JSON of the run: "
                          "per-step phase spans (data/step/digest/ckpt) plus "
-                         "the attention schedule timeline with modeled and "
-                         "achieved per-worker lanes (repro.obs.export); "
-                         "works with or without --track")
+                         "the attention schedule's modeled per-worker lanes "
+                         "(repro.obs.export); works with or without --track. "
+                         "Device time comes from a profiler trace, in which "
+                         "the same spans appear as repro.<phase>")
     ap.add_argument("--chaos", type=int, default=None, metavar="SEED",
                     help="arm a seeded repro.faults checkpoint-IO plan: "
                          "saves at random --ckpt-every multiples fail their "
@@ -119,26 +120,14 @@ def main(argv=None):
         "seq": args.seq, "microbatches": args.microbatches, "run_id": run_id,
         "seed": args.seed, "tune": args.tune, "verify": bool(args.verify)})
 
-    modeled_step_s = None
     if args.tune != "off":
         from repro.tune import tune_attention
         tres = tune_attention(seq=args.seq, head_dim=cfg.head_dim,
                               dtype=cfg.dtype_name, causal=True,
                               n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                               mode=args.tune, tracker=tracker)
-        n_rep = cfg.n_layers // len(cfg.block_pattern)
-        n_attn = n_rep * sum(1 for k in cfg.block_pattern
-                             if k.startswith("attn"))
-        # attention-only modeled step time: one schedule's makespan × every
-        # (layer, batch, head) grid instance, fwd+bwd already in the task
-        # costs.  The utilization-vs-modeled metric divides this by measured
-        # wall per step — honest about being an attention-work model, not a
-        # full-model roofline.
-        modeled_step_s = (tres.modeled_makespan_s * n_attn * args.batch
-                          * cfg.n_heads) or None
         print(f"[tune] {tres.candidate.key()} source={tres.source} "
-              f"modeled_makespan={tres.modeled_makespan_s:.3e}s "
-              f"modeled_step(attn)={modeled_step_s or 0:.3e}s", flush=True)
+              f"modeled_makespan={tres.modeled_makespan_s:.3e}s", flush=True)
 
     tcfg = S.TrainConfig(
         opt=O.OptConfig(name=args.opt, lr=args.lr, total_steps=args.steps),
@@ -214,7 +203,7 @@ def main(argv=None):
         print(f"[chaos] armed {plan.key()} ({len(plan)} flaky saves; all "
               "within the writer's retry budget)", flush=True)
 
-    meter = StepMeter(modeled_step_s=modeled_step_s)
+    meter = StepMeter()
     # --trace-out implies per-step sync + events too: span durations must
     # time real step work, not dispatch
     tracking = args.track is not None or args.trace_out is not None
@@ -309,8 +298,7 @@ def main(argv=None):
     if args.trace_out is not None:
         from repro.obs import export as EX
         events = EX.spans_to_trace(trace_mem.events, process_name=run_id)
-        events += EX.attention_timeline(args.seq, cfg.head_dim, causal=True,
-                                        measure=True)
+        events += EX.attention_timeline(args.seq, cfg.head_dim, causal=True)
         EX.write_trace(args.trace_out, events)
         print(f"[trace] {len(events)} events -> {args.trace_out}", flush=True)
     tracker.close()

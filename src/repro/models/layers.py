@@ -24,6 +24,7 @@ from repro.dist.sharding import shard
 from repro.kernels.decode import paged_attention
 from repro.kernels.ops import attention as attention_op
 from repro.models.module import ParamDef as PD
+from repro.obs.layers import scope
 
 F32 = jnp.float32
 
@@ -143,9 +144,10 @@ def _sdpa_full(q, k, v, cfg, causal, window=None, segment_ids=None):
         qt = shard(qt, "batch", None, "seq_sp", None)
         kt = shard(kt, "batch", None, None, None)
         vt = shard(vt, "batch", None, None, None)
-    out = attention_op(qt, kt, vt, causal=causal, impl=cfg.attention_impl,
-                       schedule=cfg.dash_schedule, chunk_q=cfg.attn_chunk_q,
-                       mask=mask, segment_ids=segment_ids)
+    with scope("attention"):
+        out = attention_op(qt, kt, vt, causal=causal, impl=cfg.attention_impl,
+                           schedule=cfg.dash_schedule, chunk_q=cfg.attn_chunk_q,
+                           mask=mask, segment_ids=segment_ids)
     if seq_shard:
         out = shard(out, "batch", None, "seq_sp", None)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
@@ -206,9 +208,10 @@ def _canonical_paged_sdpa(q, k, v, cfg, window=None, segment_ids=None):
         kv_seg = jnp.pad(segment_ids.astype(jnp.int32), ((0, 0), (0, pad)),
                          constant_values=-1).reshape(b * n_pg, ps)
         segment_ids = segment_ids.astype(jnp.int32)
-    return paged_attention(q, pool(k), pool(v), table, qpos,
-                           window=window or None, q_segments=segment_ids,
-                           kv_segments=kv_seg)
+    with scope("attention"):
+        return paged_attention(q, pool(k), pool(v), table, qpos,
+                               window=window or None, q_segments=segment_ids,
+                               kv_segments=kv_seg)
 
 
 def attention_block(p, x, cfg, *, positions=None, cache=None, cache_pos=None,
@@ -273,8 +276,9 @@ def attention_block(p, x, cfg, *, positions=None, cache=None, cache_pos=None,
             start = (jax.lax.axis_index(fold.scope_axis()) * h_loc) // g
             kp = jax.lax.dynamic_slice_in_dim(k_pages, start, kv_needed, -2)
             vp = jax.lax.dynamic_slice_in_dim(v_pages, start, kv_needed, -2)
-        out = paged_attention(q, kp, vp, paged["page_table"], positions,
-                              window=window or None)
+        with scope("attention"):
+            out = paged_attention(q, kp, vp, paged["page_table"], positions,
+                                  window=window or None)
         out = out.reshape(x.shape[:-1] + (h_loc * cfg.head_dim,))
         # canonical fold (virtual shard = one head): the serve-side wo
         # reduction is identical at every TP degree including 1
@@ -421,12 +425,14 @@ def _det_embed_lookup(vocab: int, dtype_name: str):
 
 
 def apply_embed(p, tokens, cfg):
-    table = p["tok"].astype(cfg.dtype)
-    if cfg.det_embed_grad:
-        emb = _det_embed_lookup(table.shape[0], str(table.dtype))(table, tokens)
-    else:
-        emb = table[tokens]
-    return shard(emb, "batch", "seq", "act_embed")
+    with scope("embed"):
+        table = p["tok"].astype(cfg.dtype)
+        if cfg.det_embed_grad:
+            emb = _det_embed_lookup(table.shape[0], str(table.dtype))(table,
+                                                                      tokens)
+        else:
+            emb = table[tokens]
+        return shard(emb, "batch", "seq", "act_embed")
 
 
 def lm_head_defs(cfg):

@@ -29,6 +29,7 @@ from repro.models import mamba as M
 from repro.models import moe as MOE
 from repro.models import xlstm as X
 from repro.models.module import ParamDef, init_tree, spec_tree, stacked
+from repro.obs.layers import scope
 
 F32 = jnp.float32
 
@@ -77,11 +78,13 @@ def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
     aux = jnp.zeros((), F32)
     new_cache: Dict[str, Any] = {}
     if kind in ("attn", "attn_moe", "attn_cross"):
-        h, c_attn = L.attention_block(
-            p["attn"], L.apply_norm(p["ln1"], x, cfg), cfg, positions=positions,
-            cache=None if cache is None else cache.get("attn"),
-            cache_pos=cache_pos, causal=causal, paged=paged,
-            segment_ids=segment_ids)
+        with scope("attn_proj"):
+            h, c_attn = L.attention_block(
+                p["attn"], L.apply_norm(p["ln1"], x, cfg), cfg,
+                positions=positions,
+                cache=None if cache is None else cache.get("attn"),
+                cache_pos=cache_pos, causal=causal, paged=paged,
+                segment_ids=segment_ids)
         x = x + h
         x = checkpoint_name(x, "attn_out")
         if c_attn is not None:
@@ -91,14 +94,15 @@ def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
                 p["xattn"], L.apply_norm(p["ln_x"], x, cfg), cfg,
                 positions=positions, cross_x=cross_x, causal=False)
             x = x + hx
-        y_in = checkpoint_name(
-            L.apply_norm(p["ln2"], x, cfg), "ffn_in")
-        if kind == "attn_moe":
-            y, aux = _moe(p["moe"], y_in, cfg)
-            if cfg.n_shared_experts:
-                y = y + L.apply_mlp(p["shared_mlp"], y_in, cfg)
-        else:
-            y = L.apply_mlp(p["mlp"], y_in, cfg)
+        with scope("mlp"):
+            y_in = checkpoint_name(
+                L.apply_norm(p["ln2"], x, cfg), "ffn_in")
+            if kind == "attn_moe":
+                y, aux = _moe(p["moe"], y_in, cfg)
+                if cfg.n_shared_experts:
+                    y = y + L.apply_mlp(p["shared_mlp"], y_in, cfg)
+            else:
+                y = L.apply_mlp(p["mlp"], y_in, cfg)
         x = x + y
     elif kind in ("mamba", "mamba_moe"):
         h, c_m = M.apply_mamba(p["mamba"], L.apply_norm(p["ln1"], x, cfg), cfg,
@@ -287,8 +291,9 @@ def _forward_body(params, batch, cfg, *, remat, remat_policy):
                              caches=None, cache_pos=None, cross_x=cross_x,
                              remat=remat, remat_policy=remat_policy,
                              segment_ids=batch.get("segment_ids"))
-    x = L.apply_norm(params["ln_f"], x, cfg)
-    logits = _lm_logits(params, x, cfg)
+    with scope("head_loss"):
+        x = L.apply_norm(params["ln_f"], x, cfg)
+        logits = _lm_logits(params, x, cfg)
     if cfg.frontend == "vision":  # logits for text positions only
         logits = logits[:, -batch["tokens"].shape[1]:]
     return logits, aux
@@ -417,8 +422,9 @@ def paged_step(params, caches, tokens, positions, page_table, write_pages,
         x, new_caches, _ = _apply_stack(params["blocks"], x, cfg,
                                         positions=positions, caches=caches,
                                         cache_pos=0, cross_x=None, paged=paged)
-        x = L.apply_norm(params["ln_f"], x, cfg)
-        return _lm_logits(params, x, cfg), new_caches
+        with scope("head_loss"):
+            x = L.apply_norm(params["ln_f"], x, cfg)
+            return _lm_logits(params, x, cfg), new_caches
 
 
 def loss_fn(params, batch, cfg, *, remat=False, remat_policy="none"):
@@ -430,10 +436,11 @@ def loss_fn(params, batch, cfg, *, remat=False, remat_policy="none"):
     # below are elementwise-masked sums over the vocab axis, which XLA fuses
     # (iota-compare-select-reduce) without materializing a gathered or fp32 copy —
     # a take_along_axis here would all-gather the vocab axis instead.
-    viota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-    gold = jnp.sum(jnp.where(viota == labels[..., None].clip(0),
-                             logits.astype(F32), 0.0), axis=-1)
-    lse = jax.nn.logsumexp(logits.astype(F32), axis=-1)
-    mask = (labels >= 0).astype(F32)
-    ce = jnp.sum((lse - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with scope("head_loss"):
+        viota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        gold = jnp.sum(jnp.where(viota == labels[..., None].clip(0),
+                                 logits.astype(F32), 0.0), axis=-1)
+        lse = jax.nn.logsumexp(logits.astype(F32), axis=-1)
+        mask = (labels >= 0).astype(F32)
+        ce = jnp.sum((lse - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     return ce + cfg.moe_aux_weight * aux, {"ce": ce, "aux": aux}

@@ -5,20 +5,14 @@ Generalizes :mod:`repro.core.gantt` (terminal ASCII, write-only) to a
 ``chrome://tracing`` and scrub the same per-worker lanes the paper's Gantt
 figures draw.  Three producers:
 
-  * :func:`schedule_to_trace` — a ``core.schedules.Schedule`` rendered twice:
-    a **modeled** process (one thread per worker, task compute/reduce phases
+  * :func:`schedule_to_trace` — a ``core.schedules.Schedule`` as a
+    **modeled** process: one thread per worker, task compute/reduce phases
     at the simulator's ``(c, r)`` roofline costs — the exact DAG
-    ``tune/model.py`` ranks candidates with) beside an **achieved** process
-    (the same layout uniformly stretched so the modeled makespan lands on
-    the measured kernel wall time).  Per-tile achieved times are not
-    host-observable — a Pallas kernel is one opaque dispatch — so the
-    achieved lane shows where the modeled schedule *would* place each tile
-    at the measured rate; the honest number is the stall factor
-    (``achieved_s / modeled_makespan``) recorded in every event's args.
+    ``tune/model.py`` ranks candidates with.  It is a model, not a
+    measurement: the kernels' device time is read from a profiler trace.
   * :func:`attention_timeline` — convenience wrapper: build the schedule for
-    a (seq, head_dim, mask) attention shape, cost it with
-    ``tune.model.task_costs``, optionally *measure* the fused fwd+bwd kernel
-    for the achieved lane.
+    a (seq, head_dim, mask) attention shape and cost it with
+    ``tune.model.task_costs``.
   * :func:`spans_to_trace` — a recorded span stream (``repro.obs.span``
     events out of a tracker JSONL / ``MemoryTracker``) as one process with
     one thread per lane.
@@ -35,10 +29,8 @@ from typing import Dict, List, Optional, Sequence
 
 _US = 1e6                      # trace timestamps are microseconds (float ok)
 PID_MODELED = 1
-PID_ACHIEVED = 2
 PID_RUN = 3
 PROCESS_MODELED = "schedule (modeled)"
-PROCESS_ACHIEVED = "schedule (achieved)"
 
 
 def _meta(pid: int, name: str, tids: Optional[Dict[int, str]] = None) -> List[Dict]:
@@ -52,13 +44,11 @@ def _meta(pid: int, name: str, tids: Optional[Dict[int, str]] = None) -> List[Di
 
 # --------------------------------------------------------------- schedules
 def schedule_to_trace(schedule, c: float, r: float,
-                      achieved_s: Optional[float] = None,
                       link: float = 0.0) -> List[Dict]:
-    """Trace events for one schedule: modeled lanes (+ achieved if measured).
+    """Trace events for one schedule's modeled lanes.
 
     ``c``/``r`` are the simulator task costs in **seconds** (see
-    ``tune.model.task_costs``); ``achieved_s`` is the measured wall time the
-    scheduled work actually took.  Returns a flat event list — wrap with
+    ``tune.model.task_costs``).  Returns a flat event list — wrap with
     :func:`make_trace` / :func:`write_trace`.
     """
     from repro.core.simulator import simulate
@@ -68,20 +58,12 @@ def schedule_to_trace(schedule, c: float, r: float,
     for w, chain in enumerate(schedule.chains):
         for task in chain:
             worker_of[task] = w
-    stretch = (achieved_s / res.makespan
-               if achieved_s and res.makespan > 0 else None)
     base_args = {"modeled_makespan_s": res.makespan,
                  "modeled_utilization": res.utilization,
                  "c_s": c, "r_s": r}
-    if achieved_s is not None:
-        base_args["achieved_s"] = achieved_s
-        base_args["stall_factor"] = (achieved_s / res.makespan
-                                     if res.makespan > 0 else 0.0)
 
     tids = {w: f"worker {w}" for w in range(schedule.n_workers)}
     events = _meta(PID_MODELED, PROCESS_MODELED, tids)
-    if stretch is not None:
-        events += _meta(PID_ACHIEVED, PROCESS_ACHIEVED, tids)
 
     for task, (cs, rs, re) in sorted(res.task_times.items()):
         h, kv, q = task
@@ -93,28 +75,16 @@ def schedule_to_trace(schedule, c: float, r: float,
             events.append({"ph": "X", "pid": PID_MODELED, "tid": w,
                            "name": name, "cat": cat,
                            "ts": t0 * _US, "dur": dur * _US, "args": args})
-            if stretch is not None:
-                events.append({"ph": "X", "pid": PID_ACHIEVED, "tid": w,
-                               "name": name, "cat": cat,
-                               "ts": t0 * stretch * _US,
-                               "dur": dur * stretch * _US, "args": args})
     return events
 
 
 def attention_timeline(seq: int, head_dim: int, *, causal: bool = True,
                        block: int = 64, schedule: str = "symmetric_shift_or_shift",
-                       mask=None, measure: bool = False,
-                       reps: int = 3) -> List[Dict]:
-    """Schedule-timeline events for one attention shape.
+                       mask=None) -> List[Dict]:
+    """Modeled schedule-timeline events for one attention shape.
 
-    Resolves the schedule like ``kernels.ops.dash_attention`` does, costs it
-    with the roofline model, and — when ``measure=True`` — times the jitted
-    reference attention backward (``kernels.ref.mha_bwd``, the same honest
-    measured quantity ``bench_kernel_bwd`` reports; the Pallas kernel itself
-    is interpret-mode on CPU and not timeable) at ``(1, seq, head_dim)`` f32,
-    min over ``reps`` after a compile warmup, for the achieved lane.  The
-    measurement is dense causal/full — a block-sparse ``mask`` shapes the
-    modeled lanes only.
+    Resolves the schedule like ``kernels.ops.dash_attention`` does and costs
+    it with the roofline model.
     """
     from repro.core.schedules import cached_schedule
     from repro.tune.model import task_costs
@@ -127,29 +97,7 @@ def attention_timeline(seq: int, head_dim: int, *, causal: bool = True,
     sched = cached_schedule(name, n, n_heads=1, causal=causal, n_q=n,
                             mask=mask, block_q=block, block_k=block)
     c, r = task_costs(block, block, head_dim)
-
-    achieved = None
-    if measure:
-        import time
-
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels import ref
-
-        ks = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, k, v, do = (jax.random.normal(kk, (1, seq, head_dim), jnp.float32)
-                       for kk in ks)
-        out, lse = ref.mha_fwd(q, k, v, causal)
-        f = jax.jit(lambda *a: ref.mha_bwd(*a, causal=causal))
-        jax.block_until_ready(f(q, k, v, out, lse, do))     # compile
-        best = float("inf")
-        for _ in range(max(1, reps)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(f(q, k, v, out, lse, do))
-            best = min(best, time.perf_counter() - t0)
-        achieved = best
-    return schedule_to_trace(sched, c, r, achieved_s=achieved)
+    return schedule_to_trace(sched, c, r)
 
 
 # ------------------------------------------------------------ span streams
@@ -226,8 +174,8 @@ def validate_trace(obj, require_processes: Sequence[str] = ()) -> List[str]:
     non-empty list; every event has a known ``ph``; complete events carry
     numeric non-negative ``ts``/``dur`` plus ``name``/``pid``/``tid``;
     metadata events name a process or thread.  ``require_processes`` asserts
-    specific process lanes exist (CI requires the modeled + achieved
-    schedule lanes in a ``--trace-out`` artifact).
+    specific process lanes exist (CI requires the modeled schedule lanes
+    in a ``--trace-out`` artifact).
     """
     problems: List[str] = []
     if not isinstance(obj, dict):
@@ -277,7 +225,7 @@ def main(argv=None) -> int:
     p.add_argument("--validate", nargs="+", metavar="TRACE.json",
                    help="schema-check trace files; nonzero exit on failure")
     p.add_argument("--require-schedule-lanes", action="store_true",
-                   help="with --validate: require modeled+achieved schedule "
+                   help="with --validate: require the modeled schedule "
                         "process lanes")
     p.add_argument("--from-events", metavar="EVENTS.jsonl",
                    help="convert a tracker JSONL span stream to a trace")
@@ -287,8 +235,7 @@ def main(argv=None) -> int:
 
     rc = 0
     if args.validate:
-        require = ((PROCESS_MODELED, PROCESS_ACHIEVED)
-                   if args.require_schedule_lanes else ())
+        require = (PROCESS_MODELED,) if args.require_schedule_lanes else ()
         for path in args.validate:
             with open(path) as f:
                 obj = json.load(f)

@@ -126,26 +126,14 @@ class Histogram:
         return out
 
 
-def utilization_vs_modeled(modeled_s: float, achieved_s: float) -> float:
-    """Achieved-vs-modeled-makespan utilization: the fraction of measured
-    wall time the DAG model says the scheduled work needs. 1.0 = the hardware
-    delivers exactly the modeled makespan; < 1 = overhead/stalls the model
-    does not account for; > 1 usually means the model's roofline constants
-    are stale for this part."""
-    return modeled_s / achieved_s if achieved_s > 0 else 0.0
-
-
 @dataclasses.dataclass
 class StepMeter:
-    """Per-step throughput + utilization aggregator for training loops.
+    """Per-step throughput aggregator for training loops.
 
     ``update(tokens, dt_s)`` per step; ``event()`` returns the tracker payload
-    (instantaneous + running tokens/s, step ms, utilization-vs-modeled when a
-    modeled per-step makespan is configured — see
-    ``launch/train.py --tune/--track``)."""
+    (instantaneous + running tokens/s, step ms — see
+    ``launch/train.py --track``)."""
 
-    modeled_step_s: Optional[float] = None      # modeled makespan of one step's
-                                                # scheduled attention work
     tokens: int = 0
     total_s: float = 0.0
     steps: int = 0
@@ -161,18 +149,13 @@ class StepMeter:
         return self.event()
 
     def event(self) -> Dict[str, float]:
-        out = {
+        return {
             "tokens_per_s": self.last_tokens_per_s,
             "tokens_per_s_avg": self.tokens / self.total_s
             if self.total_s > 0 else 0.0,
             "step_ms": self.last_step_s * 1e3,
             "steps": float(self.steps),
         }
-        if self.modeled_step_s is not None:
-            out["modeled_step_s"] = self.modeled_step_s
-            out["utilization_vs_modeled"] = utilization_vs_modeled(
-                self.modeled_step_s, self.last_step_s)
-        return out
 
 
 class MetricSet:

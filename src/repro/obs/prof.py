@@ -41,6 +41,8 @@ Span phases (the README §Observability schema table mirrors this):
 
 Span ids are sha256 of ``(run_id, scope, phase)`` — see
 :mod:`repro.obs.span` — so two runs of the same program agree on every id.
+Each phase also appears in a ``jax.profiler`` trace as the host event
+``repro.<phase>`` (``repro.train_step``, ``repro.decode``, …).
 """
 from __future__ import annotations
 

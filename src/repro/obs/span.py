@@ -13,11 +13,17 @@ repo's bitwise story:
     ``dur_s``, relative to the tracer's first observation) are observations
     *about* the run, carried in the event data, and are the only
     nondeterministic fields in a span record;
+  * **on the profiler's clock** — each span also opens a
+    ``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` when the
+    tracker is armed or a profiler trace is active, so a device trace shows
+    the host's phases beside the kernels. Each annotation records its own
+    interval, so spans that overlap without nesting (a request and its
+    queue wait) land as they happened;
   * **disarmed is free** — against a :class:`~repro.obs.tracker.NoopTracker`
-    the tracer never reads the clock and never allocates a ``Span``, so an
-    untracked run does not even perturb host timing, let alone a token bit
-    (tests/test_obs_prof.py proves bitwise invariance on the spec and
-    sharded serve paths).
+    and with no profiler trace active the tracer never reads the clock and
+    never allocates a ``Span`` or an annotation, so an untracked run does not
+    even perturb host timing, let alone a token bit (tests/test_obs_prof.py
+    proves bitwise invariance on the spec and sharded serve paths).
 
 Span event record (one ``"span"`` event per *completed* span)::
 
@@ -36,9 +42,19 @@ import dataclasses
 import hashlib
 import time
 from contextlib import contextmanager
-from typing import Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional
+
+from jax._src.lib import _profiler
+from jax.profiler import TraceAnnotation
 
 from repro.obs.tracker import NoopTracker, Tracker
+
+ANNOTATION_PREFIX = "repro."
+
+
+def profiling() -> bool:
+    """True while a profiler trace is being recorded in this process."""
+    return _profiler.TraceMe.is_enabled()
 
 
 def span_id(run_id: str, scope: str, phase: str) -> str:
@@ -63,6 +79,7 @@ class Span:
     lane: Optional[str] = None
     step: Optional[int] = None
     attrs: Dict = dataclasses.field(default_factory=dict)
+    annotation: Any = None     # the open ``repro.<phase>`` profiler event
 
 
 class SpanTracer:
@@ -97,17 +114,25 @@ class SpanTracer:
     def begin(self, phase: str, scope: str, *, parent: Optional[Span] = None,
               lane: Optional[str] = None, step: Optional[int] = None,
               **attrs) -> Optional[Span]:
-        """Open a span; returns ``None`` when disarmed (``end(None)`` no-ops)."""
-        if not self.armed:
+        """Open a span; returns ``None`` when disarmed and no profiler trace
+        is active (``end(None)`` no-ops)."""
+        if not self.armed and not profiling():
             return None
+        annotation = TraceAnnotation(ANNOTATION_PREFIX + phase)
+        annotation.__enter__()
         return Span(id=span_id(self.run_id, scope, phase), phase=phase,
                     scope=scope, begin_s=self.now(),
                     parent_id=parent.id if parent is not None else None,
-                    lane=lane, step=step, attrs=dict(attrs))
+                    lane=lane, step=step, attrs=dict(attrs),
+                    annotation=annotation)
 
     def end(self, span: Optional[Span], **attrs) -> None:
-        """Close a span and emit the ``"span"`` event (no-op on ``None``)."""
+        """Close a span's profiler event and, when armed, emit the
+        ``"span"`` event (no-op on ``None``)."""
         if span is None:
+            return
+        span.annotation.__exit__(None, None, None)
+        if not self.armed:
             return
         data: Dict = {"phase": span.phase, "scope": span.scope,
                       "span_id": span.id, "parent_id": span.parent_id,

@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.dist import fold
 from repro.models import transformer as T
+from repro.obs.layers import scope
 from repro.serve.kv_cache import PagedKVCache, PagedLayout
 from repro.serve.scheduler import FCFSScheduler, Request
 
@@ -124,21 +125,22 @@ def _sample_rows(logits, req_ids, steps, scfg: SampleConfig):
     Logprob contract (pinned; asserted in tests/test_serve_invariance.py):
     greedy reports ``log_softmax(raw logits)[tok]``; sampled reports
     ``log_softmax(transformed logits)[tok]``."""
-    logits = logits.astype(jnp.float32)
-    if scfg.temperature == 0.0:
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        lp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
-                                 tok[:, None], axis=-1)[:, 0]
-        return tok, lp
-    base = jax.random.PRNGKey(scfg.seed)
+    with scope("sample"):
+        logits = logits.astype(jnp.float32)
+        if scfg.temperature == 0.0:
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            lp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                                     tok[:, None], axis=-1)[:, 0]
+            return tok, lp
+        base = jax.random.PRNGKey(scfg.seed)
 
-    def one(row, rid, t):
-        k = jax.random.fold_in(jax.random.fold_in(base, rid), t)
-        tl = _transform_logits(row, scfg)
-        tok = jax.random.categorical(k, tl).astype(jnp.int32)
-        return tok, jax.nn.log_softmax(tl)[tok]
+        def one(row, rid, t):
+            k = jax.random.fold_in(jax.random.fold_in(base, rid), t)
+            tl = _transform_logits(row, scfg)
+            tok = jax.random.categorical(k, tl).astype(jnp.int32)
+            return tok, jax.nn.log_softmax(tl)[tok]
 
-    return jax.vmap(one)(logits, req_ids, steps)
+        return jax.vmap(one)(logits, req_ids, steps)
 
 
 def _sample(logits, scfg: SampleConfig, step_key):
@@ -157,10 +159,16 @@ class Engine:
         self.cfg, self.params, self.max_seq, self.scfg = cfg, params, max_seq, scfg
         self.last_decode_steps = 0        # poll-every-step reference count
         self.dispatched_decode_steps = 0  # decodes actually dispatched
-        self._prefill = jax.jit(
-            lambda p, b: T.prefill_step(p, b, cfg, max_seq=max_seq))
-        self._decode = jax.jit(
-            lambda p, c, t, pos, cx: T.decode_step(p, c, t, pos, cfg, cross_x=cx))
+
+        def prefill(p, b):
+            return T.prefill_step(p, b, cfg, max_seq=max_seq)
+
+        def decode(p, c, t, pos, cx):
+            return T.decode_step(p, c, t, pos, cfg, cross_x=cx)
+
+        # named functions, so the modules are jit_prefill / jit_decode
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
 
     def generate(self, batch, n_tokens: int):
         """batch: dict with 'tokens' (B, S_prompt) (+ frontend inputs).
@@ -227,8 +235,14 @@ def _paged_step_fn(cfg):
     """Shared jitted paged step — cached per (hashable, frozen) config so many
     engine instances (the invariance suite builds dozens) reuse compilations.
     Compiled like the sharded step (``fold.exact_jit``), so the two round
-    alike."""
-    return fold.exact_jit(functools.partial(T.paged_step, cfg=cfg))
+    alike. A named function, so the compiled module is ``jit_paged_step``."""
+
+    def paged_step(params, caches, tokens, positions, page_table,
+                   write_pages, write_offsets):
+        return T.paged_step(params, caches, tokens, positions, page_table,
+                            write_pages, write_offsets, cfg=cfg)
+
+    return fold.exact_jit(paged_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -243,8 +257,12 @@ def _sampler_fn(scfg: SampleConfig):
     the post-temperature/top-k softmax; greedy reports the **raw** softmax —
     the pinned contract on :func:`_sample_rows`) — part of the
     topology-invariance contract, so the mesh-axis tests can assert sampled
-    logprobs bitwise too."""
-    return jax.jit(functools.partial(_sample_rows, scfg=scfg))
+    logprobs bitwise too. The compiled module is ``jit_sample_rows``."""
+
+    def sample_rows(logits, req_ids, steps):
+        return _sample_rows(logits, req_ids, steps, scfg)
+
+    return jax.jit(sample_rows)
 
 
 @dataclasses.dataclass

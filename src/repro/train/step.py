@@ -24,6 +24,7 @@ from repro.configs.base import ModelConfig
 from repro.dist import compression, fold
 from repro.dist.sharding import logical_to_spec, spec_tree_to_pspecs
 from repro.models import transformer as T
+from repro.obs.layers import scope
 from repro.train import optimizer as O
 from repro.verify import digest as V
 
@@ -145,8 +146,10 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
         new_state = dict(state)
         if tcfg.grad_compression == "int8":
             grads, new_state["ef"] = compression.compress_grads(grads, state["ef"])
-        new_p, new_opt, gnorm = O.opt_update(tcfg.opt, grads, state["opt"],
-                                             params, state["step"])
+        with scope("optimizer"):
+            new_p, new_opt, gnorm = O.opt_update(tcfg.opt, grads,
+                                                 state["opt"], params,
+                                                 state["step"])
         new_state.update(params=new_p, opt=new_opt, step=state["step"] + 1)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm,
                        lr=O.lr_at(tcfg.opt, state["step"]))

@@ -292,24 +292,25 @@ def test_armed_profiler_bitwise_noop_sharded_tp():
 
 # ------------------------------------------------------------ Perfetto export
 def test_schedule_timeline_validates_with_both_lanes():
-    events = EX.attention_timeline(128, 32, causal=True, measure=False)
-    # modeled lane always present; synthesize the achieved lane
+    """The modeled lane: one thread per worker, every task's compute and
+    reduce phase at the simulator's times, and no other process."""
     from repro.core.schedules import cached_schedule
+    from repro.core.simulator import simulate
     from repro.tune.model import task_costs
     n = 128 // 64
     sched = cached_schedule("symmetric_shift", n, 1, True, n)
     c, r = task_costs(64, 64, 32)
-    events = EX.schedule_to_trace(sched, c, r, achieved_s=1e-3)
-    probs = EX.validate_trace(
-        EX.make_trace(events),
-        require_processes=(EX.PROCESS_MODELED, EX.PROCESS_ACHIEVED))
+    events = EX.schedule_to_trace(sched, c, r)
+    assert events == EX.attention_timeline(128, 32, causal=True)
+    probs = EX.validate_trace(EX.make_trace(events),
+                              require_processes=(EX.PROCESS_MODELED,))
     assert probs == [], probs
-    # the achieved lane is the modeled layout under a uniform stretch
     xs = [e for e in events if e.get("ph") == "X"]
-    modeled = sorted(e["ts"] for e in xs if e["pid"] == EX.PID_MODELED)
-    achieved = sorted(e["ts"] for e in xs if e["pid"] == EX.PID_ACHIEVED)
-    stretch = [a / m for a, m in zip(achieved, modeled) if m > 0]
-    assert all(abs(s - stretch[0]) < 1e-9 for s in stretch)
+    assert {e["pid"] for e in xs} == {EX.PID_MODELED}
+    res = simulate(sched, c, r)
+    assert len(xs) == 2 * len(res.task_times)
+    end = max(e["ts"] + e["dur"] for e in xs)
+    assert end == pytest.approx(res.makespan * 1e6)
 
 
 def test_validate_trace_rejects_garbage():
@@ -318,7 +319,7 @@ def test_validate_trace_rejects_garbage():
                                                "pid": 1, "tid": 1,
                                                "ts": -5, "dur": 1}]})
     assert EX.validate_trace({"traceEvents": [{"ph": "?", "ts": 0}]})
-    good = EX.make_trace(EX.attention_timeline(128, 32, measure=False))
+    good = EX.make_trace(EX.attention_timeline(128, 32))
     assert EX.validate_trace(good) == []
     assert EX.validate_trace(good, require_processes=("no-such-process",))
 

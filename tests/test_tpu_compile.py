@@ -113,3 +113,29 @@ def test_dash_attention_grad_compiles(compile_for, causal, d):
 
     x = ((1, BH, S, d), jnp.bfloat16)
     compile_for(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+def test_attention_scope_names_every_kernel(compile_for):
+    """Through the model's call site (``layers._sdpa_full``, which opens the
+    ``attention`` layer scope): every Mosaic kernel of the compiled
+    ``grad(dash_attention)`` maps to that layer, forward and backward, and
+    keeps the instruction name the trace readers match it by."""
+    import re
+
+    from repro.configs import registry
+    from repro.models import layers as L
+    from repro.obs.layers import op_layers
+
+    cfg = registry.get("stablelm-1.6b").replace(attention_impl="pallas")
+
+    def loss(q, k, v):
+        return L._sdpa_full(q, k, v, cfg, True).astype(jnp.float32).sum()
+
+    x = ((1, S, BH, D), jnp.bfloat16)
+    hlo = compile_for(jax.grad(loss, argnums=(0, 1, 2)), x, x, x).as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*"
+                         r'custom_call_target="tpu_custom_call"', hlo, re.M)
+    layers = op_layers(hlo)
+    assert kernels and {layers[k] for k in kernels} == {"attention"}
+    for name in ("flash_fwd", "_flash_bwd_worker_call", "_fold_combine_call"):
+        assert any(re.match(rf"{name}\b", k) for k in kernels), (name, kernels)

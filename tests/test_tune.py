@@ -346,8 +346,8 @@ def test_tuned_bitwise_equals_handpicked(arch, causal, tmp_path):
 @pytest.mark.slow
 def test_launch_train_tune_track_smoke(tmp_path):
     """`--tune sim --track --verify` end to end: the tracker JSONL carries the
-    tuner decision, per-step throughput + utilization-vs-modeled, the live
-    fingerprint stream, and the cache/run summaries (ISSUE acceptance)."""
+    tuner decision, per-step throughput, the live fingerprint stream, and
+    the cache/run summaries."""
     track = tmp_path / "run.jsonl"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "stablelm-1.6b",
@@ -368,7 +368,7 @@ def test_launch_train_tune_track_smoke(tmp_path):
     assert [e["step"] for e in steps] == [1, 2, 3]
     for e in steps:
         assert e["tokens_per_s"] > 0
-        assert 0 <= e["utilization_vs_modeled"]
+        assert "utilization_vs_modeled" not in e
         assert "loss" in e and "grad_norm" in e
     # the tuner decision is recorded and the fingerprint chain stayed clean
     choice = next(e for e in events if e["event"] == "tune_choice")
