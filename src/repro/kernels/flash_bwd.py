@@ -59,6 +59,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.schedules import Schedule
 from repro.kernels.flash_fwd import info_inputs, split_info_refs
 from repro.kernels.gqa import kv_head_index, validate_group
+from repro.kernels.vmem import lanes
 
 NEG_INF = -1e30
 
@@ -93,7 +94,7 @@ def dq_lanes(d: int) -> int:
     up to 128. The TPU DMA moves whole 128-lane tiles, so at head_dim 64 the
     (block_q, d) read-modify-write window is refused; the kernels store dQ in
     the first ``d`` lanes and the wrapper slices the rest away."""
-    return -(-d // 128) * 128
+    return lanes(d)
 
 
 def _row_to_col(x):
@@ -435,6 +436,23 @@ def fold_combine(partials, visited, block, interpret=False):
 # --------------------------------------------------------------------------- #
 # host wrapper
 # --------------------------------------------------------------------------- #
+def worker_grid(schedule: Schedule, worker_parallel: bool = True):
+    """The schedule's :meth:`~Schedule.worker_chains` when the backward runs
+    worker-parallel, else ``None`` (the serialized realization).
+
+    Non-registry schedules degrade to the serialized realization instead of
+    changing numerics or crashing: a worker visiting one q column twice would
+    regroup that column's partial sums vs the serialized fold, and a worker
+    with no head-0 tasks has no grid row at all."""
+    if not worker_parallel:
+        return None
+    try:
+        wc = schedule.worker_chains()
+    except ValueError:
+        return None
+    return wc if wc["single_visit"] else None
+
+
 def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
               sm_scale=None, block_q=128, block_k=128, interpret=False,
               worker_parallel=True, n_heads: Optional[int] = None,
@@ -491,17 +509,8 @@ def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse, delta = lse.reshape(bh, 1, sq), delta.reshape(bh, 1, sq)
 
-    if worker_parallel:
-        # Non-registry schedules degrade to the serialized realization instead
-        # of changing numerics or crashing: a worker visiting one q column
-        # twice would regroup that column's partial sums vs the serialized
-        # fold, and a worker with no head-0 tasks has no grid row at all.
-        try:
-            wc = schedule.worker_chains()
-            worker_parallel = wc["single_visit"]
-        except ValueError:
-            worker_parallel = False
-    if worker_parallel:
+    wc = worker_grid(schedule, worker_parallel)
+    if wc is not None:
         dq_part, dk, dv = _flash_bwd_worker_call(
             q, k, v, do, lse, delta,
             jnp.asarray(wc["kv_ids"]), jnp.asarray(wc["q_ids"]),

@@ -21,6 +21,7 @@ in ascending query-head order (fixed-order fold; deterministic).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -30,9 +31,15 @@ import jax.numpy as jnp
 
 from repro.core.schedules import Schedule, cached_schedule, make_schedule
 from repro.kernels import ref as ref_mod
-from repro.kernels.flash_bwd import flash_bwd
+from repro.kernels.flash_bwd import flash_bwd, worker_grid
 from repro.kernels.flash_fwd import flash_fwd
 from repro.kernels.gqa import validate_group
+from repro.kernels.vmem import best_block
+
+# The tiling the last traced dash_attention call resolved (``last_tiling``)
+# and the tracker ``log_tilings`` arms to receive each one. Host-side only:
+# written while a call is traced, never read by the computation.
+_TILING = {"last": None, "tracker": None}
 
 
 def _flatten(x):  # (B, H, S, D) -> (BH, S, D)
@@ -94,9 +101,29 @@ def _bwd_rule(causal, schedule_name, sm_scale, block, interpret, mask,
 _dash_attention.defvjp(_fwd_rule, _bwd_rule)
 
 
+def last_tiling() -> Optional[dict]:
+    """What the last ``dash_attention`` call traced in this process runs
+    on: its ``seq``, ``head_dim`` and ``dtype``, the square ``block``, the
+    schedule and its (``n_kv``, ``n_q``) tiles, and the backward's
+    ``grid`` with its ``real_steps`` and no-op ``sentinel_steps``."""
+    return _TILING["last"]
+
+
+@contextlib.contextmanager
+def log_tilings(tracker):
+    """Log the tiling of every ``dash_attention`` call traced inside the
+    block into ``tracker``, one ``"attn_tiling"`` event each."""
+    prev, _TILING["tracker"] = _TILING["tracker"], tracker
+    try:
+        yield tracker
+    finally:
+        _TILING["tracker"] = prev
+
+
 def dash_attention(q, k, v, causal: bool = False,
                    schedule: str = "symmetric_shift_or_shift",
-                   sm_scale: Optional[float] = None, block: int = 128,
+                   sm_scale: Optional[float] = None,
+                   block: Optional[int] = None,
                    interpret: bool = False, mask=None, tune=False,
                    worker_parallel: bool = True):
     """DASH attention with deterministic scheduled backward.
@@ -113,7 +140,11 @@ def dash_attention(q, k, v, causal: bool = False,
         "symmetric_shift_or_shift" (pick the paper-optimal one for the mask).
         For block-sparse masks this selects the *placement*: "shift" (the
         generalized optimum) or "fa3" (ascending baseline).
-      block: square tile size (MXU-aligned; 128 default).
+      block: square tile size (MXU-aligned). By default the largest of
+        (512, 256, 128) that divides S and whose forward and backward VMEM
+        footprints fit (:func:`repro.kernels.vmem.best_block`, from S, the
+        head dim and the dtype); 128 under a block-sparse ``mask``, whose
+        PARTIAL-tile waste grows with the tile.
       tune: ``True``/"sim" lets :func:`repro.tune.tune_attention` resolve
         (schedule, block, worker_parallel) from the modeled makespan for this
         (shape, dtype, mask) key; "measure" takes the winner of a measured
@@ -150,6 +181,9 @@ def dash_attention(q, k, v, causal: bool = False,
         schedule = cand.schedule
         block = cand.block_q          # candidates are square-tiled
         worker_parallel = cand.worker_parallel
+    elif block is None:
+        block = (128 if mask is not None else
+                 best_block(s, d, q.dtype.itemsize, causal))
     if schedule == "symmetric_shift_or_shift":
         schedule = ("shift" if mask is not None else
                     "symmetric_shift" if causal else "shift")
@@ -157,8 +191,35 @@ def dash_attention(q, k, v, causal: bool = False,
         raise ValueError(
             f"block-sparse masks take placement 'shift' or 'fa3'; got "
             f"{schedule!r}")
+    if s % block:
+        raise ValueError(f"sequence length {s} is not a multiple of the "
+                         f"{block} tile")
+    _record_tiling(q, causal, schedule, block, mask, worker_parallel)
     return _dash_attention(q, k, v, causal, schedule, sm_scale, block,
                            interpret, mask, worker_parallel)
+
+
+def _record_tiling(q, causal, schedule, block, mask, worker_parallel):
+    b, h, s, d = q.shape
+    # the same memoized schedule the backward rule builds
+    sch = cached_schedule(schedule, s // block, n_heads=1, causal=causal,
+                          mask=mask, block_q=block, block_k=block)
+    wc = worker_grid(sch, worker_parallel)
+    if wc is None:
+        grid = [b * h, int(sch.prefetch_arrays(0)[0].shape[0])]
+        real = math.prod(grid)
+    else:
+        grid = [b * h, *(int(n) for n in wc["valid"].shape)]
+        real = b * h * int(wc["valid"].sum())
+    rec = {"seq": s, "head_dim": d, "dtype": jnp.dtype(q.dtype).name,
+           "schedule": schedule, "block": block,
+           "n_kv": sch.n_kv, "n_q": sch.n_q,
+           "realization": "serialized" if wc is None else "worker_parallel",
+           "grid": grid, "real_steps": real,
+           "sentinel_steps": math.prod(grid) - real}
+    _TILING["last"] = rec
+    if _TILING["tracker"] is not None:
+        _TILING["tracker"].log("attn_tiling", rec)
 
 
 def _grouped_logits_mask(logits, causal):

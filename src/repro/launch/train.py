@@ -72,7 +72,7 @@ def main(argv=None):
     ap.add_argument("--track", default=None, metavar="JSONL",
                     help="write a repro.obs event stream here: per-step "
                          "throughput, fingerprint + divergence events (with "
-                         "--verify), tuner decisions")
+                         "--verify), tuner decisions, attention tilings")
     ap.add_argument("--track-reference", default=None, metavar="JSONL",
                     help="a previous run's --track file; with --verify, the "
                          "live fingerprint stream is compared against it and "
@@ -209,12 +209,14 @@ def main(argv=None):
     tracking = args.track is not None or args.trace_out is not None
     tokens_per_step = args.batch * args.seq
     from repro.faults import armed_checkpoint
+    from repro.kernels.ops import log_tilings
     pending = None
     t0 = time.time()
     # armed_checkpoint(None) is a no-op; when --chaos armed an injector, the
     # hook must stay installed through the *final* async save's join — the
-    # writer thread consults it mid-write.
-    with armed_checkpoint(injector):
+    # writer thread consults it mid-write. The first step traces the model:
+    # each attention call's tiling lands in the tracker as "attn_tiling".
+    with armed_checkpoint(injector), log_tilings(tracker):
         for step in range(start, args.steps):
             if args.die_at_step is not None and step == args.die_at_step:
                 print(f"simulated failure at step {step}", flush=True)

@@ -26,6 +26,7 @@ SHAPES = [  # (bh, seq, d, block)
     (1, 256, 64, 128),
     (2, 512, 128, 128),
     (3, 384, 64, 128),   # non-power-of-two tiles (3 tiles)
+    (1, 1024, 64, 512),  # the tile the default rule picks at long S
     (2, 256, 96, 128),   # ragged head dim
 ]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -44,7 +45,7 @@ def test_fwd_matches_ref(bh, s, d, blk, dtype, causal):
     np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse), atol=1e-2, rtol=1e-3)
 
 
-@pytest.mark.parametrize("bh,s,d,blk", SHAPES[:3])
+@pytest.mark.parametrize("bh,s,d,blk", SHAPES[:4])
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("causal,sched", [
     (False, "fa3"), (False, "descending"), (False, "shift"),
@@ -63,14 +64,21 @@ def test_bwd_matches_ref(bh, s, d, blk, dtype, causal, sched):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), err_msg=nm, **tol)
 
 
-@pytest.mark.parametrize("causal,sched", [(True, "symmetric_shift"), (False, "shift")])
-def test_bwd_bitwise_deterministic(causal, sched):
+@pytest.mark.parametrize("causal,sched,s,blk", [
+    pytest.param(True, "symmetric_shift", 256, 128, id="True-symmetric_shift"),
+    pytest.param(False, "shift", 256, 128, id="False-shift"),
+    pytest.param(True, "symmetric_shift", 1024, 512,
+                 id="True-symmetric_shift-1024-512"),
+    pytest.param(False, "shift", 1024, 512, id="False-shift-1024-512"),
+])
+def test_bwd_bitwise_deterministic(causal, sched, s, blk):
     """Same schedule => bitwise identical grads across runs (paper Table 1, det column)."""
-    q, k, v, do = (_rand((2, 256, 64), jnp.bfloat16, i + 10) for i in range(4))
-    out, lse = flash_fwd(q, k, v, causal=causal, interpret=True)
-    schedule = make_schedule(sched, 2, 1, causal)
+    q, k, v, do = (_rand((2, s, 64), jnp.bfloat16, i + 10) for i in range(4))
+    out, lse = flash_fwd(q, k, v, causal=causal, block_q=blk, block_k=blk,
+                         interpret=True)
+    schedule = make_schedule(sched, s // blk, 1, causal)
     f = lambda: flash_bwd(q, k, v, out, lse, do, schedule, causal=causal,
-                          interpret=True)
+                          block_q=blk, block_k=blk, interpret=True)
     a, b = f(), f()
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -24,7 +24,20 @@ from repro.kernels.flash_bwd import dq_lanes, flash_bwd, fold_combine
 from repro.kernels.flash_fwd import flash_fwd
 from repro.kernels.ops import dash_attention
 
-BH, S, D, BLOCK = 32, 4096, 64, 128
+BH, S, D = 32, 4096, 64
+# the fixed tile of old and the one the default rule picks at S 4096, D 64
+BLOCKS = (128, 512)
+
+
+def _cases(ids):
+    """``(id, value)`` pairs crossed with every block: the 128 case keeps the
+    bare id, the others append the block to it."""
+    return [pytest.param(value, block,
+                         id=name if block == 128 else f"{name}-{block}")
+            for block in BLOCKS for name, value in ids]
+
+
+CAUSAL = _cases([("causal", True), ("full", False)])
 
 
 @pytest.fixture(scope="module")
@@ -68,39 +81,43 @@ def compile_for(one_chip):
     cc.reset_cache()
 
 
-def _schedule(causal):
+def _schedule(causal, block):
     return cached_schedule("symmetric_shift" if causal else "shift",
-                           S // BLOCK, n_heads=1, causal=causal,
-                           block_q=BLOCK, block_k=BLOCK)
+                           S // block, n_heads=1, causal=causal,
+                           block_q=block, block_k=block)
 
 
-@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_flash_fwd_compiles(compile_for, causal):
+@pytest.mark.parametrize("causal,block", CAUSAL)
+def test_flash_fwd_compiles(compile_for, causal, block):
     x = ((BH, S, D), jnp.bfloat16)
-    compile_for(lambda q, k, v: flash_fwd(q, k, v, causal=causal), x, x, x)
+    compile_for(lambda q, k, v: flash_fwd(q, k, v, causal=causal,
+                                          block_q=block, block_k=block),
+                x, x, x)
 
 
-@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("causal,block", CAUSAL)
 @pytest.mark.parametrize("worker_parallel", [True, False],
                          ids=["worker_parallel", "serialized"])
-def test_flash_bwd_compiles(compile_for, worker_parallel, causal):
-    sch = _schedule(causal)
+def test_flash_bwd_compiles(compile_for, worker_parallel, causal, block):
+    sch = _schedule(causal, block)
     x = ((BH, S, D), jnp.bfloat16)
 
     def bwd(q, k, v, out, lse, do):
         return flash_bwd(q, k, v, out, lse, do, sch, causal=causal,
+                         block_q=block, block_k=block,
                          worker_parallel=worker_parallel)
 
     compile_for(bwd, x, x, x, x, ((BH, S), jnp.float32), x)
 
 
-@pytest.mark.parametrize("width", [dq_lanes(D), D], ids=["dq", "gqa_dkdv"])
-def test_fold_combine_compiles(compile_for, width):
+@pytest.mark.parametrize("width,block",
+                         _cases([("dq", dq_lanes(D)), ("gqa_dkdv", D)]))
+def test_fold_combine_compiles(compile_for, width, block):
     """At both widths the backward hands it: the worker-parallel dQ partials
     padded to ``dq_lanes(D)``, and the GQA group's dK/dV at ``D``."""
-    n_workers = _schedule(True).worker_chains()["kv_ids"].shape[0]
-    visited = np.ones((n_workers, S // BLOCK), np.int32)
-    compile_for(lambda p: fold_combine(p, visited, BLOCK),
+    n_workers = _schedule(True, block).worker_chains()["kv_ids"].shape[0]
+    visited = np.ones((n_workers, S // block), np.int32)
+    compile_for(lambda p: fold_combine(p, visited, block),
                 ((BH, n_workers, S, width), jnp.float32))
 
 

@@ -40,17 +40,24 @@ def _bwd(sched, causal, dtype, worker_parallel, bh=2, s=512, d=64, blk=128):
                      worker_parallel=worker_parallel)
 
 
-@pytest.mark.parametrize("sched,causal", SCHEDULES)
+@pytest.mark.parametrize("sched,causal,s,blk", [
+    *(pytest.param(sched, causal, 512, 128, id=f"{sched}-{causal}")
+      for sched, causal in SCHEDULES),
+    # the tile the default rule picks at long S
+    pytest.param("symmetric_shift", True, 1024, 512,
+                 id="symmetric_shift-True-1024-512"),
+    pytest.param("shift", False, 1024, 512, id="shift-False-1024-512"),
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_parallel_bitwise_matches_serialized(sched, causal, dtype):
+def test_parallel_bitwise_matches_serialized(sched, causal, s, blk, dtype):
     """W=n parallel realization == W=1 serialized realization, bit for bit.
 
     Both paths reduce every dQ column worker-major (the serialized core plays
     chains concatenated ascending; the parallel combine folds partials in
     ascending worker order), and registry schedules give each worker at most
     one task per column — so the fp32 folds have identical association."""
-    par = _bwd(sched, causal, dtype, worker_parallel=True)
-    ser = _bwd(sched, causal, dtype, worker_parallel=False)
+    par = _bwd(sched, causal, dtype, worker_parallel=True, s=s, blk=blk)
+    ser = _bwd(sched, causal, dtype, worker_parallel=False, s=s, blk=blk)
     for got, want, nm in zip(par, ser, ("dq", "dk", "dv")):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=f"{sched} {nm}")
