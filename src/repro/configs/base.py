@@ -8,6 +8,20 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (HF transformers' ``rope_type: yarn``): the
+    frequencies are blended between interpolated (÷ ``factor``) and
+    extrapolated by a linear ramp between the correction dims that
+    ``beta_fast`` and ``beta_slow`` rotations give at ``original_max``
+    positions, and cos/sin are multiplied by ``attention_factor``."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | vlm | audio
@@ -24,12 +38,18 @@ class ModelConfig:
     rope_pct: float = 1.0
     pos_embed: str = "rope"        # rope | learned | none
     norm: str = "rmsnorm"
+    norm_eps: float = 1e-5
     activation: str = "silu"
     attention_impl: str = "xla"    # xla | pallas (DASH kernels)
     dash_schedule: str = "symmetric_shift_or_shift"
     attn_chunk_q: int = 1024       # q-chunked attention above this seq (HBM bound)
     attn_window: int = 0           # sliding-window size in tokens (0 = full);
                                    # lowers as masks.SlidingWindow on both impls
+    layer_windows: Tuple[int, ...] = ()  # per block_pattern position: its
+                                   # attn_window (0 = full); () = attn_window
+                                   # at every position
+    rope_yarn: Optional[Yarn] = None  # YaRN rope; with layer_windows, on the
+                                   # full-attention positions only
     packed_inputs: bool = False    # batches carry segment_ids/positions from
                                    # the deterministic sequence packer
                                    # (data.pipeline.pack_documents): attention
@@ -44,6 +64,10 @@ class ModelConfig:
     moe_impl: str = "einsum"       # einsum (MeshTF, paper-era baseline) | gather
     moe_groups: int = 1            # >1: split seq into token-parallel dispatch
                                    # groups (GShard-style; pairs with seq_sp)
+    moe_held: Tuple[int, ...] = () # expert ids this chip holds of the
+                                   # n_experts routed (models.moe.
+                                   # apply_moe_held: no token dropped);
+                                   # () = every expert, einsum/gather paths
     # ssm
     ssm_expand: int = 2
     ssm_state_dim: int = 16
@@ -103,6 +127,7 @@ class ModelConfig:
             d_ff=256 if self.d_ff else 0, vocab=512, vocab_pad=128,
             n_experts=4 if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            moe_held=self.moe_held[:2],
             frontend_dim=64 if self.frontend_dim else 0,
             frontend_len=16 if self.frontend_len else 0,
             max_seq=256, ssm_chunk=32,

@@ -30,6 +30,7 @@ ALIASES = {
     "jamba-1.5-large-398b": "jamba_1_5_large",
     "whisper-base": "whisper_base",
     "dash-paper": "dash_paper",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
 

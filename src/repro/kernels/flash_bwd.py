@@ -436,19 +436,41 @@ def fold_combine(partials, visited, block, interpret=False):
 # --------------------------------------------------------------------------- #
 # host wrapper
 # --------------------------------------------------------------------------- #
-def worker_grid(schedule: Schedule, worker_parallel: bool = True):
+# The worker-parallel realization keeps one float32 dQ partial per worker
+# over the whole sequence, (BH, W, S, dq_lanes(d)). Above this many bytes,
+# half of a v5e's 16 GiB, the rest of a step no longer fits beside them and
+# the backward takes the serialized realization, whose one dQ buffer is W
+# times smaller. The limit lies a factor of two from both sides of the
+# shapes benchmarked: causal at S 8192, d 128, 64 heads and 512 tiles keeps
+# 4 GiB of partials (16 workers); the sliding window of 1024 at 128 tiles
+# would keep 17.2 GB (64 workers). On a one-core chip the worker axis runs
+# in sequence anyway.
+DQ_PARTIALS_MAX_BYTES = 8 * 2**30
+
+
+def dq_bytes(bh: int, seq: int, d: int) -> int:
+    """Bytes of one float32 dQ buffer of the kernels, (BH, S, dq_lanes(d))."""
+    return bh * seq * dq_lanes(d) * 4
+
+
+def worker_grid(schedule: Schedule, worker_parallel: bool,
+                dq_buffer_bytes: int):
     """The schedule's :meth:`~Schedule.worker_chains` when the backward runs
     worker-parallel, else ``None`` (the serialized realization).
 
     Non-registry schedules degrade to the serialized realization instead of
     changing numerics or crashing: a worker visiting one q column twice would
     regroup that column's partial sums vs the serialized fold, and a worker
-    with no head-0 tasks has no grid row at all."""
+    with no head-0 tasks has no grid row at all. So does a grid whose dQ
+    partials, one ``dq_buffer_bytes`` buffer (:func:`dq_bytes`) per worker,
+    would pass :data:`DQ_PARTIALS_MAX_BYTES`."""
     if not worker_parallel:
         return None
     try:
         wc = schedule.worker_chains()
     except ValueError:
+        return None
+    if wc["valid"].shape[0] * dq_buffer_bytes > DQ_PARTIALS_MAX_BYTES:
         return None
     return wc if wc["single_visit"] else None
 
@@ -509,7 +531,7 @@ def flash_bwd(q, k, v, out, lse, do, schedule: Schedule, causal=False,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse, delta = lse.reshape(bh, 1, sq), delta.reshape(bh, 1, sq)
 
-    wc = worker_grid(schedule, worker_parallel)
+    wc = worker_grid(schedule, worker_parallel, dq_bytes(bh, sq, d))
     if wc is not None:
         dq_part, dk, dv = _flash_bwd_worker_call(
             q, k, v, do, lse, delta,

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from repro.core.schedules import Schedule, cached_schedule, make_schedule
 from repro.kernels import ref as ref_mod
-from repro.kernels.flash_bwd import flash_bwd, worker_grid
+from repro.kernels.flash_bwd import dq_bytes, flash_bwd, worker_grid
 from repro.kernels.flash_fwd import flash_fwd
 from repro.kernels.gqa import validate_group
 from repro.kernels.vmem import best_block
@@ -103,9 +103,13 @@ _dash_attention.defvjp(_fwd_rule, _bwd_rule)
 
 def last_tiling() -> Optional[dict]:
     """What the last ``dash_attention`` call traced in this process runs
-    on: its ``seq``, ``head_dim`` and ``dtype``, the square ``block``, the
-    schedule and its (``n_kv``, ``n_q``) tiles, and the backward's
-    ``grid`` with its ``real_steps`` and no-op ``sentinel_steps``."""
+    on: its ``seq``, ``head_dim`` and ``dtype``, the ``mask`` (``causal``,
+    ``full`` or the spec) with its ``window`` (a ``SlidingWindow``'s, else
+    None), the square ``block``, the schedule and its (``n_kv``, ``n_q``)
+    tiles, and the backward's ``realization``, ``grid`` with its
+    ``real_steps`` and no-op ``sentinel_steps``, and ``dq_partial_bytes``:
+    the float32 dQ buffers it keeps (one per worker, or the serialized
+    realization's one)."""
     return _TILING["last"]
 
 
@@ -204,7 +208,8 @@ def _record_tiling(q, causal, schedule, block, mask, worker_parallel):
     # the same memoized schedule the backward rule builds
     sch = cached_schedule(schedule, s // block, n_heads=1, causal=causal,
                           mask=mask, block_q=block, block_k=block)
-    wc = worker_grid(sch, worker_parallel)
+    one = dq_bytes(b * h, s, d)
+    wc = worker_grid(sch, worker_parallel, one)
     if wc is None:
         grid = [b * h, int(sch.prefetch_arrays(0)[0].shape[0])]
         real = math.prod(grid)
@@ -212,11 +217,15 @@ def _record_tiling(q, causal, schedule, block, mask, worker_parallel):
         grid = [b * h, *(int(n) for n in wc["valid"].shape)]
         real = b * h * int(wc["valid"].sum())
     rec = {"seq": s, "head_dim": d, "dtype": jnp.dtype(q.dtype).name,
+           "mask": ("causal" if causal else "full") if mask is None
+           else repr(mask),
+           "window": getattr(mask, "window", None),
            "schedule": schedule, "block": block,
            "n_kv": sch.n_kv, "n_q": sch.n_q,
            "realization": "serialized" if wc is None else "worker_parallel",
            "grid": grid, "real_steps": real,
-           "sentinel_steps": math.prod(grid) - real}
+           "sentinel_steps": math.prod(grid) - real,
+           "dq_partial_bytes": one * (1 if wc is None else grid[1])}
     _TILING["last"] = rec
     if _TILING["tracker"] is not None:
         _TILING["tracker"].log("attn_tiling", rec)

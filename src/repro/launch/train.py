@@ -23,6 +23,7 @@ from repro.ckpt import checkpoint as C
 from repro.configs import registry
 from repro.data.pipeline import DataConfig, make_source
 from repro.launch.compile_cache import use_compile_cache
+from repro.models import moe as MOE
 from repro.train import optimizer as O
 from repro.train import step as S
 
@@ -72,7 +73,8 @@ def main(argv=None):
     ap.add_argument("--track", default=None, metavar="JSONL",
                     help="write a repro.obs event stream here: per-step "
                          "throughput, fingerprint + divergence events (with "
-                         "--verify), tuner decisions, attention tilings")
+                         "--verify), tuner decisions, attention tilings, "
+                         "held-expert MoE routing counts")
     ap.add_argument("--track-reference", default=None, metavar="JSONL",
                     help="a previous run's --track file; with --verify, the "
                          "live fingerprint stream is compared against it and "
@@ -252,12 +254,16 @@ def main(argv=None):
                 payload = meter.update(tokens_per_step, time.time() - ts)
                 payload.update(S.step_event(metrics))
                 tracker.log("step", payload, step=step + 1)
+                if "moe_routing" in metrics:
+                    tracker.log("moe_routing", MOE.routing_event(
+                        metrics["moe_routing"]), step=step + 1)
             if alarm is not None and "state_fingerprint" in metrics:
                 if alarm.observe(step + 1, metrics["state_fingerprint"]):
                     print(f"[verify] fingerprint divergence at step "
                           f"{step + 1} (see tracker)", flush=True)
             if (step + 1) % args.log_every == 0 or step == start:
-                m = {k: float(v) for k, v in metrics.items()}
+                m = {k: float(v) for k, v in metrics.items()
+                     if k != "moe_routing"}
                 dt = (time.time() - t0) / max(1, step + 1 - start)
                 print(f"step {step + 1} loss={m['loss']:.4f} "
                       f"gnorm={m['grad_norm']:.3f} lr={m['lr']:.2e} "

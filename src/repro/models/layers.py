@@ -43,8 +43,9 @@ def norm_defs(cfg, d=None):
     return {"scale": PD((d,), (None,), "ones", F32)}
 
 
-def apply_norm(p, x, cfg, eps=1e-5):
+def apply_norm(p, x, cfg):
     xf = x.astype(F32)
+    eps = cfg.norm_eps
     if "bias" in p:  # layernorm
         mu = jnp.mean(xf, -1, keepdims=True)
         var = jnp.var(xf, -1, keepdims=True)
@@ -56,8 +57,33 @@ def apply_norm(p, x, cfg, eps=1e-5):
 
 
 # ----------------------------------------------------------------- RoPE
-def rope(x, positions, theta: float, pct: float = 1.0):
-    """Rotary embedding on the leading `pct` fraction of head_dim.
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's (dim // 2,) inverse frequencies (HF transformers'
+    ``_compute_yarn_parameters``): extrapolated (θ^(-2i/dim)) below the
+    correction dim of ``beta_fast`` rotations over ``original_max``
+    positions, interpolated (÷ ``factor``) above that of ``beta_slow``, a
+    linear ramp between. Float32, computed on the host."""
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolation = 1.0 - ramp
+    inv = (1.0 / (yarn.factor * pos_freqs) * (1.0 - extrapolation)
+           + 1.0 / pos_freqs * extrapolation)
+    return inv.astype(np.float32)
+
+
+def rope(x, positions, theta: float, pct: float = 1.0, yarn=None):
+    """Rotary embedding on the leading `pct` fraction of head_dim; with
+    ``yarn`` (:class:`repro.configs.base.Yarn`) its frequencies and its
+    cos/sin scaled by the attention factor.
     x: (..., S, H, D); positions: (..., S) int32."""
     d = x.shape[-1]
     dr = int(d * pct)
@@ -66,9 +92,14 @@ def rope(x, positions, theta: float, pct: float = 1.0):
     dr -= dr % 2
     xr, xp = x[..., :dr], x[..., dr:]
     half = dr // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=F32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=F32) / half)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(dr, theta, yarn))
     ang = positions.astype(F32)[..., None, None] * freqs        # (..., S, 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = xr[..., :half], xr[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return jnp.concatenate([out.astype(x.dtype), xp], -1)
@@ -106,8 +137,8 @@ def _project_qkv(p, xq, xkv, cfg, q_pos, kv_pos, use_rope=True):
     k = k.reshape(xkv.shape[:-1] + (hk, hd)).astype(cfg.dtype)
     v = v.reshape(xkv.shape[:-1] + (hk, hd)).astype(cfg.dtype)
     if use_rope and cfg.rope_pct > 0:
-        q = rope(q, q_pos, cfg.rope_theta, cfg.rope_pct)
-        k = rope(k, kv_pos, cfg.rope_theta, cfg.rope_pct)
+        q = rope(q, q_pos, cfg.rope_theta, cfg.rope_pct, cfg.rope_yarn)
+        k = rope(k, kv_pos, cfg.rope_theta, cfg.rope_pct, cfg.rope_yarn)
     return q, k, v
 
 

@@ -15,17 +15,21 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.dist.sharding import shard
 from repro.models.module import ParamDef as PD
+from repro.obs.layers import scope
 
 F32 = jnp.float32
 
 
 def moe_defs(cfg):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all ``n_experts``; expert weights for the held experts
+    (``cfg.moe_held``), else for all of them."""
+    d, f, e = cfg.d_model, cfg.d_ff, len(cfg.moe_held) or cfg.n_experts
     p = {
-        "router": PD((d, e), ("embed", None), "scaled", F32),
+        "router": PD((d, cfg.n_experts), ("embed", None), "scaled", F32),
         "w_up": PD((e, d, f), ("experts", "embed", "mlp")),
         "w_down": PD((e, f, d), ("experts", "mlp", "embed"), "scaled"),
     }
@@ -192,3 +196,149 @@ def apply_moe_gather(p, x, cfg) -> Tuple[jax.Array, jax.Array]:
         y = y.reshape(b0, s0, d)   # back to the seq-sharded residual layout
         return shard(y, "batch", "seq_sp", "act_embed"), aux
     return shard(y, "batch", "seq", "act_embed"), aux
+
+
+# --------------------------------------------------------------------------- #
+# one chip's expert-parallel share: held experts, no token dropped
+# --------------------------------------------------------------------------- #
+@jax.custom_vjp
+def _dispatch(xt, src, valid, pos, mask):
+    """Rows (N, D) of the grouped buffer: row r holds token ``src[r]``
+    where ``valid[r]``, else zeros. Its backward gathers each token's rows
+    (``pos``, where ``mask``) and sums them in ascending held-expert order:
+    no scatter-add."""
+    return jnp.where(valid[:, None], jnp.take(xt, src, axis=0), 0)
+
+
+def _dispatch_fwd(xt, src, valid, pos, mask):
+    return _dispatch(xt, src, valid, pos, mask), (pos, mask)
+
+
+def _dispatch_bwd(res, g):
+    pos, mask = res
+    dx = jnp.zeros((pos.shape[0], g.shape[1]), F32)
+    for j in range(pos.shape[1]):
+        dx = dx + jnp.where(mask[:, j, None],
+                            jnp.take(g, pos[:, j], axis=0).astype(F32), 0.0)
+    return dx.astype(g.dtype), None, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, gates, src, row_expert, valid, pos, mask):
+    """y[t] = Σ_j gates[t, j] · out[pos[t, j]] over the held experts j that
+    token t routed to, summed in ascending j, in float32. The backward
+    gathers too: d_out[r] = gates[src[r], row_expert[r]] · dy[src[r]]."""
+    y = jnp.zeros((pos.shape[0], out.shape[1]), F32)
+    for j in range(pos.shape[1]):
+        y = y + jnp.where(mask[:, j, None], gates[:, j, None]
+                          * jnp.take(out, pos[:, j], axis=0).astype(F32), 0.0)
+    return y
+
+
+def _combine_fwd(out, gates, src, row_expert, valid, pos, mask):
+    return (_combine(out, gates, src, row_expert, valid, pos, mask),
+            (out, gates, src, row_expert, valid, pos, mask))
+
+
+def _combine_bwd(res, dy):
+    out, gates, src, row_expert, valid, pos, mask = res
+    g_row = gates[src, row_expert]
+    d_out = jnp.where(valid[:, None],
+                      g_row[:, None] * jnp.take(dy, src, axis=0), 0.0)
+    d_gates = jnp.stack(
+        [jnp.where(mask[:, j], jnp.sum(
+            dy * jnp.take(out, pos[:, j], axis=0).astype(F32), -1), 0.0)
+         for j in range(pos.shape[1])], axis=1)
+    return (d_out.astype(out.dtype), d_gates, None, None, None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def apply_moe_held(p, x, cfg):
+    """The part of an MoE layer that the experts ``cfg.moe_held`` give, as
+    one chip of an expert-parallel deployment computes it.
+    x: (B, S, D) -> (y, aux_loss, routing counts).
+
+    The router is the whole layer's: float32 softmax over all
+    ``n_experts``, top ``top_k`` (ties to the lowest index), renormalised
+    over the k (``renorm_topk``). Every slot routed to a held expert is
+    computed: the grouped buffer has a row for each (token, held expert)
+    pair, so no capacity can drop one. Rows are ordered by held expert,
+    then token (a stable sort), and the products are grouped matmuls
+    (``lax.ragged_dot``) over the held experts' rows only. The combine
+    weights each expert's output by its gate and sums a token's experts in
+    ascending order (:func:`_combine`); neither direction scatters.
+
+    aux: Switch-style load balance over all experts, n_experts · Σ_e f_e ·
+    P_e, with f_e the share of tokens that route a slot to e (Σ_e f_e =
+    top_k) and P_e the mean router probability. Routing counts, per held
+    expert: ``load`` (slots routed to it); ``elsewhere`` (slots routed to
+    experts another chip holds) and ``dropped`` (slots the buffer could not
+    hold: 0 by construction). The grouped products run in the
+    ``moe_experts`` layer scope, inside the block's ``moe`` scope.
+    """
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.top_k
+    nh, t = len(cfg.moe_held), b * s
+    xt = x.reshape(t, d)
+    logits = jnp.dot(xt.astype(F32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)    # (T, E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    # top_k picks the experts; the gates are read from probs by mask and
+    # static slices, so that no gradient passes through top_k's scatter
+    _, gate_idx = jax.lax.top_k(probs, k)
+    chosen = jnp.sum(jax.nn.one_hot(gate_idx, e, dtype=F32), axis=1)  # (T, E)
+    held_of = lambda a: jnp.stack([a[:, i] for i in cfg.moe_held], axis=1)
+    mask = held_of(chosen) > 0                               # (T, J)
+    gates = jnp.where(mask, held_of(probs), 0.0)
+    if cfg.renorm_topk:
+        gates = gates / jnp.sum(probs * chosen, -1, keepdims=True)
+
+    # grouped rows: held expert major, token minor; unrouted pairs last
+    counts = jnp.sum(mask, axis=0, dtype=jnp.int32)          # (J,)
+    starts = jnp.cumsum(counts) - counts
+    key = jnp.where(mask.T, jnp.arange(nh, dtype=jnp.int32)[:, None], nh)
+    order = jnp.argsort(key.reshape(-1), stable=True)        # (J*T,)
+    src, row_expert = order % t, order // t
+    total = jnp.sum(counts)
+    valid = jnp.arange(nh * t) < total
+    pos = (starts[None, :]
+           + jnp.cumsum(mask, axis=0, dtype=jnp.int32) - 1)  # (T, J)
+    pos = jnp.where(mask, pos, 0)
+
+    xin = _dispatch(xt, src, valid, pos, mask)               # (J*T, D)
+    with scope("moe_experts"):
+        wd = cfg.dtype
+        up = jax.lax.ragged_dot(xin, p["w_up"].astype(wd), counts,
+                                preferred_element_type=F32)
+        gate_h = (jax.lax.ragged_dot(xin, p["w_gate"].astype(wd), counts,
+                                     preferred_element_type=F32)
+                  if "w_gate" in p else up)
+        h = _act(gate_h, up, cfg).astype(wd)
+        out = jax.lax.ragged_dot(h, p["w_down"].astype(wd), counts,
+                                 preferred_element_type=wd)
+    y = _combine(out, gates, src, row_expert, valid, pos, mask)
+
+    aux = e * jnp.sum(jnp.mean(chosen, axis=0) * jnp.mean(probs, axis=0))
+    routing = {"load": counts,
+               "elsewhere": jnp.int32(t * k) - total,
+               "dropped": total - jnp.sum(valid, dtype=jnp.int32)}
+    return y.astype(x.dtype).reshape(b, s, d), aux, routing
+
+
+def routing_event(routing) -> dict:
+    """The ``moe_routing`` event of one step from its metrics'
+    ``moe_routing`` ({block: counts stacked over repeats}): routed slots per
+    held expert and layer, the max and mean load, slots routed to experts
+    elsewhere and dropped slots, summed over layers. Host-side."""
+    load = np.concatenate([np.asarray(r["load"]) for r in routing.values()])
+    return {"load": load.tolist(), "max_load": int(load.max()),
+            "mean_load": float(load.mean()),
+            "elsewhere": int(sum(np.sum(r["elsewhere"])
+                                 for r in routing.values())),
+            "dropped": int(sum(np.sum(r["dropped"])
+                               for r in routing.values()))}

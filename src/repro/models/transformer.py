@@ -68,14 +68,29 @@ def _block_defs(cfg, kind: str):
 
 
 def _moe(p, x, cfg):
+    """(y, aux_loss, routing counts or None)."""
+    if cfg.moe_held:
+        return MOE.apply_moe_held(p, x, cfg)
     fn = MOE.apply_moe_gather if cfg.moe_impl == "gather" else MOE.apply_moe
-    return fn(p, x, cfg)
+    return fn(p, x, cfg) + (None,)
+
+
+def position_config(cfg, i: int):
+    """The config position ``i`` of the block pattern runs under: with
+    ``cfg.layer_windows``, its own ``attn_window`` (0: full attention),
+    and YaRN rope only where it attends in full."""
+    if not cfg.layer_windows:
+        return cfg
+    w = cfg.layer_windows[i]
+    return cfg.replace(attn_window=w, rope_yarn=None if w else cfg.rope_yarn)
 
 
 def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
                  causal=True, paged=None, segment_ids=None):
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss, routing counts of a held-expert MoE
+    layer or None)."""
     aux = jnp.zeros((), F32)
+    routing = None
     new_cache: Dict[str, Any] = {}
     if kind in ("attn", "attn_moe", "attn_cross"):
         with scope("attn_proj"):
@@ -94,11 +109,11 @@ def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
                 p["xattn"], L.apply_norm(p["ln_x"], x, cfg), cfg,
                 positions=positions, cross_x=cross_x, causal=False)
             x = x + hx
-        with scope("mlp"):
+        with scope("moe" if kind == "attn_moe" and cfg.moe_held else "mlp"):
             y_in = checkpoint_name(
                 L.apply_norm(p["ln2"], x, cfg), "ffn_in")
             if kind == "attn_moe":
-                y, aux = _moe(p["moe"], y_in, cfg)
+                y, aux, routing = _moe(p["moe"], y_in, cfg)
                 if cfg.n_shared_experts:
                     y = y + L.apply_mlp(p["shared_mlp"], y_in, cfg)
             else:
@@ -114,7 +129,7 @@ def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
             new_cache["mamba"] = c_m
         y_in = L.apply_norm(p["ln2"], x, cfg)
         if kind == "mamba_moe":
-            y, aux = _moe(p["moe"], y_in, cfg)
+            y, aux, routing = _moe(p["moe"], y_in, cfg)
         else:
             y = L.apply_mlp(p["mlp"], y_in, cfg)
         x = x + y
@@ -133,7 +148,7 @@ def _apply_block(p, x, cfg, kind: str, *, positions, cache, cache_pos, cross_x,
     # Sequence-parallel residual stream: the scan carry (= the remat-saved
     # activation stack) lives sharded over the model axis along sequence.
     x = shard(x, "batch", "seq_sp", "act_embed")
-    return x, (new_cache if cache is not None else None), aux
+    return x, (new_cache if cache is not None else None), aux, routing
 
 
 # --------------------------------------------------------------------------- #
@@ -196,32 +211,38 @@ REMAT_POLICIES = {
 def _apply_stack(blocks, x, cfg, *, positions, caches, cache_pos, cross_x,
                  causal=True, remat=False, remat_policy="none", paged=None,
                  segment_ids=None):
-    """blocks: dict of stacked param trees keyed 'b{i}_{kind}'."""
+    """blocks: dict of stacked param trees keyed 'b{i}_{kind}'.
+    Returns (x, new caches, aux loss, {block key: routing counts stacked
+    over repeats} of the held-expert MoE blocks)."""
     aux_total = jnp.zeros((), F32)
     new_caches = {} if caches is not None else None
+    routing = {}
     for key_name in sorted(blocks, key=lambda s: int(s.split("_")[0][1:])):
         kind = key_name.split("_", 1)[1]
         stacked_p = blocks[key_name]
+        bcfg = position_config(cfg, int(key_name.split("_")[0][1:]))
 
-        def body(carry, scan_in):
+        def body(carry, scan_in, bcfg=bcfg, kind=kind):
             x_, aux_ = carry
             p_, cache_ = scan_in if caches is not None else (scan_in, None)
-            x_, c_, a_ = _apply_block(p_, x_, cfg, kind, positions=positions,
-                                      cache=cache_, cache_pos=cache_pos,
-                                      cross_x=cross_x, causal=causal,
-                                      paged=paged, segment_ids=segment_ids)
-            return (x_, aux_ + a_), c_
+            x_, c_, a_, r_ = _apply_block(
+                p_, x_, bcfg, kind, positions=positions, cache=cache_,
+                cache_pos=cache_pos, cross_x=cross_x, causal=causal,
+                paged=paged, segment_ids=segment_ids)
+            return (x_, aux_ + a_), (c_, r_)
 
         if remat:
             body = jax.checkpoint(body, policy=REMAT_POLICIES[remat_policy])
         scan_xs = (stacked_p, caches[key_name]) if caches is not None else stacked_p
         n_rep = jax.tree.leaves(stacked_p)[0].shape[0]
-        (x, aux_total), cs = jax.lax.scan(
+        (x, aux_total), (cs, rs) = jax.lax.scan(
             body, (x, aux_total), scan_xs,
             unroll=n_rep if cfg.scan_unroll else 1)
         if caches is not None:
             new_caches[key_name] = cs
-    return x, new_caches, aux_total
+        if rs is not None:
+            routing[key_name] = rs
+    return x, new_caches, aux_total, routing
 
 
 # --------------------------------------------------------------------------- #
@@ -240,10 +261,10 @@ def _encode(params, cfg, frames, remat=False):
     h = L.dot(frames, params["encoder"]["frontend_proj"]).astype(ecfg.dtype)
     if ecfg.pos_embed == "learned":
         h = h + params["encoder"]["pos_embed"][: h.shape[1]].astype(ecfg.dtype)
-    h, _, _ = _apply_stack(params["encoder"]["blocks"], h, ecfg,
-                           positions=jnp.arange(h.shape[1])[None, :],
-                           caches=None, cache_pos=None, cross_x=None,
-                           causal=False, remat=remat)
+    h, _, _, _ = _apply_stack(params["encoder"]["blocks"], h, ecfg,
+                              positions=jnp.arange(h.shape[1])[None, :],
+                              caches=None, cache_pos=None, cross_x=None,
+                              causal=False, remat=remat)
     return L.apply_norm(params["encoder"]["ln_f"], h, ecfg)
 
 
@@ -270,6 +291,13 @@ def forward(params, batch, cfg, *, remat=False, remat_policy="none"):
     use the topology-invariant canonical fold, making these logits bitwise
     equal to ``ContinuousEngine`` chunked prefill at ``page_size=N``.
     """
+    logits, aux, _ = _forward(params, batch, cfg, remat=remat,
+                              remat_policy=remat_policy)
+    return logits, aux
+
+
+def _forward(params, batch, cfg, *, remat, remat_policy):
+    """(logits, aux loss, routing counts of the held-expert MoE blocks)."""
     if cfg.canonical_reductions:
         with fold.canonical_scope(page_size=cfg.canonical_reductions):
             return _forward_body(params, batch, cfg, remat=remat,
@@ -287,16 +315,16 @@ def _forward_body(params, batch, cfg, *, remat, remat_policy):
     positions = batch.get("positions")
     if positions is None:
         positions = jnp.arange(x.shape[1])[None, :]
-    x, _, aux = _apply_stack(params["blocks"], x, cfg, positions=positions,
-                             caches=None, cache_pos=None, cross_x=cross_x,
-                             remat=remat, remat_policy=remat_policy,
-                             segment_ids=batch.get("segment_ids"))
+    x, _, aux, routing = _apply_stack(
+        params["blocks"], x, cfg, positions=positions, caches=None,
+        cache_pos=None, cross_x=cross_x, remat=remat,
+        remat_policy=remat_policy, segment_ids=batch.get("segment_ids"))
     with scope("head_loss"):
         x = L.apply_norm(params["ln_f"], x, cfg)
         logits = _lm_logits(params, x, cfg)
     if cfg.frontend == "vision":  # logits for text positions only
         logits = logits[:, -batch["tokens"].shape[1]:]
-    return logits, aux
+    return logits, aux, routing
 
 
 def init_cache(cfg, batch_size: int, max_seq: int):
@@ -335,8 +363,9 @@ def prefill_step(params, batch, cfg, *, max_seq=None):
     s = x.shape[1]
     caches = init_cache(cfg, x.shape[0], max_seq or s)
     positions = jnp.arange(s)[None, :]
-    x, caches, _ = _apply_stack(params["blocks"], x, cfg, positions=positions,
-                                caches=caches, cache_pos=0, cross_x=cross_x)
+    x, caches, _, _ = _apply_stack(params["blocks"], x, cfg,
+                                   positions=positions, caches=caches,
+                                   cache_pos=0, cross_x=cross_x)
     x = L.apply_norm(params["ln_f"], x[:, -1:], cfg)
     return _lm_logits(params, x, cfg), caches, cross_x
 
@@ -349,9 +378,9 @@ def decode_step(params, caches, tokens, cache_pos, cfg, *, cross_x=None):
         x = x + jax.lax.dynamic_slice_in_dim(
             params["pos_embed"], cache_pos, 1, 0).astype(cfg.dtype)[None]
     positions = jnp.full((tokens.shape[0], 1), cache_pos, jnp.int32)
-    x, new_caches, _ = _apply_stack(params["blocks"], x, cfg, positions=positions,
-                                    caches=caches, cache_pos=cache_pos,
-                                    cross_x=cross_x)
+    x, new_caches, _, _ = _apply_stack(params["blocks"], x, cfg,
+                                       positions=positions, caches=caches,
+                                       cache_pos=cache_pos, cross_x=cross_x)
     x = L.apply_norm(params["ln_f"], x, cfg)
     return _lm_logits(params, x, cfg), new_caches
 
@@ -419,18 +448,21 @@ def paged_step(params, caches, tokens, positions, page_table, write_pages,
             x = x + params["pos_embed"][positions].astype(cfg.dtype)
         paged = dict(page_table=page_table, write_pages=write_pages,
                      write_offsets=write_offsets)
-        x, new_caches, _ = _apply_stack(params["blocks"], x, cfg,
-                                        positions=positions, caches=caches,
-                                        cache_pos=0, cross_x=None, paged=paged)
+        x, new_caches, _, _ = _apply_stack(params["blocks"], x, cfg,
+                                           positions=positions, caches=caches,
+                                           cache_pos=0, cross_x=None,
+                                           paged=paged)
         with scope("head_loss"):
             x = L.apply_norm(params["ln_f"], x, cfg)
             return _lm_logits(params, x, cfg), new_caches
 
 
 def loss_fn(params, batch, cfg, *, remat=False, remat_policy="none"):
-    """Next-token CE (+ MoE aux). batch: tokens (B,S), labels (B,S) with -100 pad."""
-    logits, aux = forward(params, batch, cfg, remat=remat,
-                          remat_policy=remat_policy)
+    """Next-token CE (+ MoE aux). batch: tokens (B,S), labels (B,S) with -100 pad.
+    The metrics carry ``moe_routing`` where the model has held-expert MoE
+    blocks (:func:`repro.models.moe.routing_event` reads it)."""
+    logits, aux, routing = _forward(params, batch, cfg, remat=remat,
+                                    remat_policy=remat_policy)
     labels = batch["labels"]
     # The (B,S,V) logits are sharded over (data, …, model/vocab). Both reductions
     # below are elementwise-masked sums over the vocab axis, which XLA fuses
@@ -443,4 +475,7 @@ def loss_fn(params, batch, cfg, *, remat=False, remat_policy="none"):
         lse = jax.nn.logsumexp(logits.astype(F32), axis=-1)
         mask = (labels >= 0).astype(F32)
         ce = jnp.sum((lse - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return ce + cfg.moe_aux_weight * aux, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux}
+    if routing:
+        metrics["moe_routing"] = routing
+    return ce + cfg.moe_aux_weight * aux, metrics

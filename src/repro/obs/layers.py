@@ -20,7 +20,12 @@ The vocabulary (where each scope sits):
   ``mlp``        the pre-norm and the MLP of a block;
   ``head_loss``  the final norm, the LM head and the cross-entropy;
   ``optimizer``  gradient clipping and the optimizer update;
-  ``sample``     the serving engine's sampler (serving only).
+  ``sample``     the serving engine's sampler (serving only);
+  ``moe``        the pre-norm, router, dispatch and combine of a held-expert
+                 MoE layer (``models/moe.apply_moe_held``);
+  ``moe_experts`` its grouped expert products, nested inside ``moe``: an op
+                 counts under the innermost scope, so ``moe`` reads the layer
+                 less its experts.
 """
 from __future__ import annotations
 
@@ -31,7 +36,7 @@ from typing import Dict, Optional, Tuple
 import jax
 
 LAYERS = ("embed", "attn_proj", "attention", "mlp", "head_loss", "optimizer",
-          "sample")
+          "sample", "moe", "moe_experts")
 TRAIN_LAYERS = LAYERS[:6]
 PREFIX = "layer."
 CONTAINERS = ("while", "conditional", "call")
@@ -82,14 +87,20 @@ _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _CALLED = re.compile(r"\b(calls|to_apply|body|condition|true_computation|"
                      r"false_computation)=%?([\w.\-]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_OPERANDS = re.compile(r"%([\w.\-]+)")
+# Kernels the TPU compiler makes itself from one primitive, named after it,
+# with an op_name that names no layer, and the one layer that calls the
+# primitive: ``lax.ragged_dot`` only in a held-expert MoE layer's products.
+COMPILER_KERNELS = {"ragged-dot": "moe_experts"}
 
 Scope = Tuple[Optional[str], str]
 
 
 def _computations(hlo_text: str):
     """({computation: [(instruction, opcode, op_name or None, fusion's
-    computation or None)]}, computations that run as sequences of device
-    ops: the entry and what control flow calls from it)."""
+    computation or None, its operands)]}, computations that run as
+    sequences of device ops: the entry and what control flow calls from
+    it)."""
     comps: Dict[str, list] = {}
     edges: Dict[str, list] = collections.defaultdict(list)
     entry, comp = None, None
@@ -118,7 +129,9 @@ def _computations(hlo_text: str):
                 edges[comp].append(callee)
         for branches in _BRANCHES.findall(line):
             edges[comp] += [b.strip().lstrip("%") for b in branches.split(",")]
-        comps[comp].append((name, opcode, op.group(1) if op else None, fused))
+        args = line[m.end():].split(")", 1)[0]
+        comps[comp].append((name, opcode, op.group(1) if op else None, fused,
+                            _OPERANDS.findall(args)))
     run, todo = set(), [entry] if entry else []
     while todo:
         c = todo.pop()
@@ -137,18 +150,44 @@ def op_scopes(hlo_text: str) -> Dict[str, Scope]:
     bodies.
 
     A fusion takes its own ``op_name``; one without takes the most common
-    scope among its fused instructions (:func:`_majority`)."""
+    scope among its fused instructions (:func:`_majority`). A kernel the
+    compiler makes itself (:data:`COMPILER_KERNELS`, such as the expansion
+    of ``lax.ragged_dot``, ``op_name="ragged-dot-none"``) names no layer: it
+    takes the layer that calls its primitive, and the pass of the
+    instructions that read its result ("backward" where only the optimizer
+    reads it: a weight's gradient)."""
     comps, run = _computations(hlo_text)
     out: Dict[str, Scope] = {}
     for comp in run:
-        for name, opcode, op_name, fused in comps[comp]:
+        made = []
+        for name, opcode, op_name, fused, _ in comps[comp]:
             if opcode in CONTAINERS:
                 continue
             if op_name is None and fused is not None:
-                out[name] = _majority(o for _, _, o, _ in comps.get(fused, ()))
+                out[name] = _majority(o for _, _, o, _, _ in
+                                      comps.get(fused, ()))
             else:
                 out[name] = op_name_scope(op_name or "")
+            layer = _compiler_kernel_layer(name, out[name])
+            if layer is not None:
+                made.append((name, layer))
+        for name, layer in made:
+            passes = collections.Counter(
+                out[u][1] for u, _, _, _, args in comps[comp]
+                if name in args
+                and out.get(u, (None,))[0] not in (None, "optimizer"))
+            out[name] = (layer, max(passes, key=passes.get) if passes
+                         else "backward")
     return out
+
+
+def _compiler_kernel_layer(name: str, scope_: Scope) -> Optional[str]:
+    if scope_[0] is not None:
+        return None
+    for prefix, layer in COMPILER_KERNELS.items():
+        if name.startswith(prefix):
+            return layer
+    return None
 
 
 def _majority(op_names) -> Scope:

@@ -28,6 +28,9 @@ from repro.train import step as S
      "rematted_computation/layer.attn_proj/layer.attention/jit(flash_fwd)/"
      "pallas_call", ("attention", "recompute")),
     ("jit(step)/layer.optimizer/mul", ("optimizer", "forward")),
+    ("jit(step)/jvp(layer.moe)/layer.moe_experts/ragged_dot_general",
+     ("moe_experts", "forward")),
+    ("jit(step)/transpose(jvp(layer.moe))/argsort", ("moe", "backward")),
     ("jit(step)/transpose(jvp())/add_any", (None, "backward")),
     ("jit(step)/jvp(layer.nonesuch)/add", (None, "forward")),
     ("", (None, "forward")),
@@ -87,6 +90,32 @@ def test_op_scopes_on_handwritten_hlo():
     assert LY.op_layers(HLO)["fusion.1"] == "head_loss"
 
 
+KERNELS = """HloModule jit_step, is_scheduled=true
+
+ENTRY %main.2 (a: f32[8], w: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %w = f32[8]{0} parameter(1)
+  %ragged-dot-none.3 = f32[8]{0} custom-call(%a, %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %act.1 = f32[8]{0} multiply(%ragged-dot-none.3, %a), metadata={op_name="jit(step)/transpose(jvp(layer.moe))/layer.moe_experts/mul"}
+  %ragged-dot-none.4 = f32[8]{0} custom-call(%act.1, %a), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %flash.1 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(layer.attention)/pallas_call"}
+  ROOT %upd.1 = f32[8]{0} subtract(%w, %ragged-dot-none.4), metadata={op_name="jit(step)/layer.optimizer/sub"}
+}
+"""
+
+
+def test_compiler_made_kernels_take_the_layer_of_their_primitive():
+    """The TPU compiler's ragged-dot kernels name no layer: they map to the
+    grouped products' layer, in the pass of the ops that read them (a
+    weight gradient, read by the optimizer alone, is backward); a kernel
+    the program names keeps its own scope."""
+    got = LY.op_scopes(KERNELS)
+    assert got["ragged-dot-none.3"] == ("moe_experts", "backward")
+    assert got["ragged-dot-none.4"] == ("moe_experts", "backward")
+    assert got["flash.1"] == ("attention", "forward")
+    assert got["upd.1"] == ("optimizer", "forward")
+
+
 def test_scope_refuses_unknown_layer():
     with pytest.raises(ValueError, match="unknown layer"):
         LY.scope("nonesuch")
@@ -121,6 +150,33 @@ def test_op_layers_name_every_train_layer(train_step_hlo):
         assert {"forward", "backward"} <= passes[layer], (layer, passes)
     # remat recomputes the blocks' forward inside the backward
     assert "recompute" in passes["mlp"]
+
+
+def test_stablelm_step_reads_no_new_layer(train_step_hlo):
+    """The MoE scopes leave a dense model's readings as they were: its ops
+    map to the six train layers and to no scope, nothing else."""
+    layers = {layer for layer, _ in LY.op_scopes(train_step_hlo).values()}
+    assert layers == set(LY.TRAIN_LAYERS) | {None}
+
+
+def test_held_moe_ops_map_to_moe_and_moe_experts():
+    """A held-expert MoE step (Mellum2 reduced): the router, dispatch and
+    combine map to ``moe``, the grouped products to ``moe_experts``, in
+    every pass; no op maps to ``mlp``."""
+    cfg = registry.get("mellum2-12b-a2.5b").reduced()
+    tcfg = S.TrainConfig(opt=O.OptConfig(name="adamw"), remat=True)
+    state = jax.eval_shape(
+        lambda: S.init_state(cfg, tcfg, jax.random.PRNGKey(0)))
+    ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    hlo = S.jit(cfg, S.make_train_step(cfg, tcfg)).lower(
+        state, {"tokens": ids, "labels": ids}).compile().as_text()
+    passes = {}
+    for layer, pass_ in LY.op_scopes(hlo).values():
+        passes.setdefault(layer, set()).add(pass_)
+    assert "mlp" not in passes
+    for layer in ("moe", "moe_experts"):
+        assert {"forward", "recompute", "backward"} <= passes[layer], (
+            layer, passes)
 
 
 def strip_metadata(hlo_text):

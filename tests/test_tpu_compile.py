@@ -156,3 +156,75 @@ def test_attention_scope_names_every_kernel(compile_for):
     assert kernels and {layers[k] for k in kernels} == {"attention"}
     for name in ("flash_fwd", "_flash_bwd_worker_call", "_fold_combine_call"):
         assert any(re.match(rf"{name}\b", k) for k in kernels), (name, kernels)
+
+
+# Mellum2-12B-A2.5B's attention: GQA 32/4 at head dim 128, S 8192, two rows
+MB, MH, MHK, MS, MD = 2, 32, 4, 8192, 128
+
+
+@pytest.mark.parametrize("window", [1024, 0], ids=["window", "causal"])
+def test_mellum_attention_compiles(compile_for, window):
+    """Forward and backward of the sliding-window (128 tiles, block-sparse
+    Shift, serialized: its 64 workers' dQ partials would take 17.2 GB) and
+    full causal layer (512 tiles, worker-parallel) at the cell's shape."""
+    from repro.kernels.ops import last_tiling
+    from repro.masks.spec import SlidingWindow
+
+    kw = dict(mask=SlidingWindow(window)) if window else dict(causal=True)
+
+    def fwd(q, k, v):
+        return dash_attention(q, k, v, **kw)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    q = ((MB, MH, MS, MD), jnp.bfloat16)
+    kv = ((MB, MHK, MS, MD), jnp.bfloat16)
+    compile_for(fwd, q, kv, kv)
+    compile_for(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    rec = last_tiling()
+    assert (rec["block"], rec["realization"], rec["window"]) == (
+        (128, "serialized", 1024) if window else
+        (512, "worker_parallel", None))
+
+
+def test_mellum_train_step_compiles_and_fits(one_chip):
+    """The whole train step of ``train-mellum2-8k`` (launch.train.build on
+    the benchmark's configuration: 4 layers, 8 held experts of 64, the
+    12,288-id vocabulary slice, 2 × 8192 tokens) compiles for one v5e at
+    the default scoped VMEM, and its arguments and temporaries fit the
+    chip's 16 GB."""
+    import json
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from bench.adapters import window_moe_decoder as A
+    from bench.models import window_moe_decoder as W
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "mellum2-12b-a2.5b-4l-ep8.json")) as f:
+        cfg = dict(json.load(f), name="mellum2-12b-a2.5b-4l-ep8")
+    with open(os.path.join(root, "bench", "traffic", "train-8k.json")) as f:
+        tr = json.load(f)
+    mc, tcfg = A.program_config(cfg), A.train_config(tr)
+    params = jax.eval_shape(lambda w: A.to_program(
+        mc, W.make_stacked(cfg, w)), jnp.zeros(2, jnp.uint32))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda p: A.init_state(p, tcfg), params))
+    ids = jax.ShapeDtypeStruct((tr["batch"], tr["seq"]), jnp.int32,
+                               sharding=one_chip)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        exe = A.build_train_step(mc, tcfg).lower(
+            state, {"tokens": ids, "labels": ids}).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    ma = exe.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert sum(x.size for x in jax.tree.leaves(params)) == 340_349_184
+    assert peak < 16e9, peak
