@@ -440,11 +440,11 @@ def fold_combine(partials, visited, block, interpret=False):
 # over the whole sequence, (BH, W, S, dq_lanes(d)). Above this many bytes,
 # half of a v5e's 16 GiB, the rest of a step no longer fits beside them and
 # the backward takes the serialized realization, whose one dQ buffer is W
-# times smaller. The limit lies a factor of two from both sides of the
-# shapes benchmarked: causal at S 8192, d 128, 64 heads and 512 tiles keeps
-# 4 GiB of partials (16 workers); the sliding window of 1024 at 128 tiles
-# would keep 17.2 GB (64 workers). On a one-core chip the worker axis runs
-# in sequence anyway.
+# times smaller. The shapes benchmarked lie a factor of two below it: at
+# S 8192, d 128, 64 heads and 512 tiles, causal and the sliding window of
+# 1024 each keep 4 GiB of partials (16 workers). The window at 128 tiles,
+# given as an explicit block, would keep 17.2 GB (64 workers), a factor of
+# two above. On a one-core chip the worker axis runs in sequence anyway.
 DQ_PARTIALS_MAX_BYTES = 8 * 2**30
 
 

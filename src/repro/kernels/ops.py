@@ -109,7 +109,9 @@ def last_tiling() -> Optional[dict]:
     tiles, and the backward's ``realization``, ``grid`` with its
     ``real_steps`` and no-op ``sentinel_steps``, and ``dq_partial_bytes``:
     the float32 dQ buffers it keeps (one per worker, or the serialized
-    realization's one)."""
+    realization's one); per (batch, head), ``computed_pairs``, every pair
+    of the tiles the kernels visit, and ``attended_pairs``, the pairs the
+    mask admits."""
     return _TILING["last"]
 
 
@@ -147,8 +149,9 @@ def dash_attention(q, k, v, causal: bool = False,
       block: square tile size (MXU-aligned). By default the largest of
         (512, 256, 128) that divides S and whose forward and backward VMEM
         footprints fit (:func:`repro.kernels.vmem.best_block`, from S, the
-        head dim and the dtype); 128 under a block-sparse ``mask``, whose
-        PARTIAL-tile waste grows with the tile.
+        head dim and the dtype); under a block-sparse ``mask``, also the
+        largest whose computed pairs (its live tiles, PARTIAL ones whole)
+        stay within ``vmem.MASK_PAIRS_MAX_RATIO`` of the 128 tiling's.
       tune: ``True``/"sim" lets :func:`repro.tune.tune_attention` resolve
         (schedule, block, worker_parallel) from the modeled makespan for this
         (shape, dtype, mask) key; "measure" takes the winner of a measured
@@ -186,8 +189,7 @@ def dash_attention(q, k, v, causal: bool = False,
         block = cand.block_q          # candidates are square-tiled
         worker_parallel = cand.worker_parallel
     elif block is None:
-        block = (128 if mask is not None else
-                 best_block(s, d, q.dtype.itemsize, causal))
+        block = best_block(s, d, q.dtype.itemsize, causal, mask=mask)
     if schedule == "symmetric_shift_or_shift":
         schedule = ("shift" if mask is not None else
                     "symmetric_shift" if causal else "shift")
@@ -225,7 +227,10 @@ def _record_tiling(q, causal, schedule, block, mask, worker_parallel):
            "realization": "serialized" if wc is None else "worker_parallel",
            "grid": grid, "real_steps": real,
            "sentinel_steps": math.prod(grid) - real,
-           "dq_partial_bytes": one * (1 if wc is None else grid[1])}
+           "dq_partial_bytes": one * (1 if wc is None else grid[1]),
+           "computed_pairs": real // (b * h) * block * block,
+           "attended_pairs": (mask.pair_counts(s, block)[1] if mask is not None
+                              else s * (s + 1) // 2 if causal else s * s)}
     _TILING["last"] = rec
     if _TILING["tracker"] is not None:
         _TILING["tracker"].log("attn_tiling", rec)

@@ -104,6 +104,14 @@ class MaskSpec:
         FULL} — the (kv, q) orientation matches the schedule's task cells."""
         return _block_map(self, n_kv, n_q, block_q, block_k)
 
+    def pair_counts(self, s: int, block: int) -> Tuple[int, int]:
+        """(computed, attended) query-key pairs of an ``s × s`` attention in
+        square ``block`` tiles: computed counts every pair of each non-EMPTY
+        tile, all of which the kernels compute (PARTIAL tiles mask-multiply);
+        attended counts the pairs the mask admits."""
+        counts = _tile_counts(self, s // block, s // block, block, block)
+        return int(np.count_nonzero(counts)) * block * block, int(counts.sum())
+
     def check(self, sq: int, sk: int = None) -> None:
         """Raise if some query row is fully masked (undefined softmax)."""
         dense = self.materialize(sq, sk)
@@ -119,15 +127,24 @@ class MaskSpec:
 
 
 @functools.lru_cache(maxsize=512)
-def _block_map(spec: MaskSpec, n_kv: int, n_q: int, block_q: int,
-               block_k: int) -> np.ndarray:
+def _tile_counts(spec: MaskSpec, n_kv: int, n_q: int, block_q: int,
+                 block_k: int) -> np.ndarray:
+    """(n_kv, n_q) count of the pairs the mask admits in each tile."""
     dense = spec.materialize(n_q * block_q, n_kv * block_k)
     if not dense.any(axis=1).all():
         spec.check(n_q * block_q, n_kv * block_k)  # raises with row detail
-    counts = dense.reshape(n_q, block_q, n_kv, block_k).sum(axis=(1, 3))
+    counts = dense.reshape(n_q, block_q, n_kv, block_k).sum(axis=(1, 3)).T
+    counts.setflags(write=False)
+    return counts
+
+
+@functools.lru_cache(maxsize=512)
+def _block_map(spec: MaskSpec, n_kv: int, n_q: int, block_q: int,
+               block_k: int) -> np.ndarray:
+    counts = _tile_counts(spec, n_kv, n_q, block_q, block_k)
     bm = np.where(counts == 0, EMPTY,
                   np.where(counts == block_q * block_k, FULL,
-                           PARTIAL)).astype(np.int8).T  # → (n_kv, n_q)
+                           PARTIAL)).astype(np.int8)
     bm.setflags(write=False)
     return bm
 

@@ -1,18 +1,20 @@
 """The square tile ``dash_attention`` resolves when no ``block`` is given.
 
-The rule reads only the call's shape, dtype and mask kind: the largest of
+The rule reads only the call's shape, dtype and mask: the largest of
 (512, 256, 128) that divides S and whose forward and backward VMEM
-footprints fit (``repro.kernels.vmem.best_block``), and 128 under a
-block-sparse mask. Each traced call records what it resolved; the calls
-here are traced with ``jax.eval_shape``, so no kernel runs.
+footprints fit (``repro.kernels.vmem.best_block``); under a block-sparse
+mask, also the largest whose computed pairs stay within
+``MASK_PAIRS_MAX_RATIO`` of the 128 tiling's. Each traced call records what
+it resolved; the calls here are traced with ``jax.eval_shape``, so no
+kernel runs.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.kernels.ops import dash_attention, last_tiling, log_tilings
-from repro.kernels.vmem import best_block
-from repro.masks.spec import SlidingWindow
+from repro.kernels.vmem import BLOCKS, MASK_PAIRS_MAX_RATIO, best_block
+from repro.masks.spec import Document, PrefixLM, SlidingWindow, streaming_mask
 from repro.obs import MemoryTracker
 
 
@@ -39,15 +41,67 @@ def test_default_block_follows_the_shape(s, d, dtype, causal, want):
 
 
 @pytest.mark.parametrize("kw,want", [
-    (dict(mask=SlidingWindow(256)), 128),
+    (dict(mask=SlidingWindow(256)), 256),
     (dict(causal=True, block=128), 128),
     (dict(causal=True, block=256), 256),
     (dict(causal=False, block=128), 128),
-], ids=["block_sparse", "explicit-128", "explicit-256", "explicit-full-128"])
+    (dict(mask=SlidingWindow(256), block=128), 128),
+], ids=["block_sparse", "explicit-128", "explicit-256", "explicit-full-128",
+        "explicit-mask-128"])
 def test_block_sparse_and_explicit_blocks(kw, want):
-    """A block-sparse spec keeps 128 (its PARTIAL-tile waste grows with the
-    tile); an explicit ``block`` is kept as given."""
+    """A block-sparse spec takes the tile its band allows: at S 1024 a
+    window of 256 computes 1.33× the 128 tiling's pairs at 256 tiles and
+    2.29× at 512. An explicit ``block`` is kept as given, under a mask too."""
     assert _trace(1024, 64, **kw)["block"] == want
+
+
+BAND_CASES = [
+    (8192, 128, SlidingWindow(1024), 512),
+    (1024, 64, SlidingWindow(256), 256),
+    (4096, 64, SlidingWindow(256), 256),
+    (1024, 64, Document.from_lengths((300, 724)), 512),
+    (4096, 64, streaming_mask(256, 16), 256),
+    (1024, 64, PrefixLM(300), 512),
+]
+BAND_IDS = ["window1024-8192", "window256-1024", "window256-4096",
+            "document-1024", "streaming-4096", "prefix-1024"]
+
+
+@pytest.mark.parametrize("s,d,mask,want", BAND_CASES, ids=BAND_IDS)
+def test_block_sparse_tile_follows_the_band(s, d, mask, want):
+    """The tile comes from the mask's own block map: Mellum2's window of
+    1024 at S 8192 takes 512 tiles (1.33× the pairs of the 128 tiling);
+    narrower bands keep a smaller tile. The record counts the pairs the
+    schedule's tiles compute and the pairs the mask admits."""
+    rec = _trace(s, d, mask=mask)
+    assert rec["block"] == want == best_block(s, d, 2, False, mask=mask)
+    assert rec["computed_pairs"] == mask.pair_counts(s, want)[0]
+    assert rec["attended_pairs"] == int(mask.materialize(s).sum())
+    assert rec["attended_pairs"] <= rec["computed_pairs"]
+
+
+@pytest.mark.parametrize("s,d,mask,want", BAND_CASES, ids=BAND_IDS)
+def test_block_sparse_tile_is_the_largest_within_the_bound(s, d, mask, want):
+    """The chosen tile computes at most ``MASK_PAIRS_MAX_RATIO`` times the
+    pairs of the 128 tiling, and every larger tile of ``BLOCKS`` that
+    divides S would compute more than that."""
+    base = mask.pair_counts(s, 128)[0]
+    assert mask.pair_counts(s, want)[0] <= MASK_PAIRS_MAX_RATIO * base
+    for b in BLOCKS:
+        if b > want and s % b == 0:
+            assert mask.pair_counts(s, b)[0] > MASK_PAIRS_MAX_RATIO * base
+
+
+@pytest.mark.parametrize("causal,attended", [(True, 1024 * 1025 // 2),
+                                             (False, 1024 * 1024)],
+                         ids=["causal", "full"])
+def test_paper_masks_count_their_pairs(causal, attended):
+    """Without a spec the record counts the causal or full pairs, and the
+    512 tiles the causal grid visits (the diagonal ones whole)."""
+    rec = _trace(1024, 64, causal=causal)
+    assert rec["attended_pairs"] == attended
+    assert rec["block"] == 512
+    assert rec["computed_pairs"] == (3 if causal else 4) * 512 * 512
 
 
 def test_tune_resolves_its_own_block():

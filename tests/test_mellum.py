@@ -191,24 +191,37 @@ def test_yarn_cos_sin_match_the_formula():
                                atol=3e-3)
 
 
-@pytest.mark.parametrize("worker_parallel", [False, True])
-def test_window_backward_matches_dense_oracle(worker_parallel):
+@pytest.mark.parametrize("s,window,block,worker_parallel", [
+    pytest.param(512, 160, None, False, id="False"),
+    pytest.param(512, 160, None, True, id="True"),
+    pytest.param(1024, 384, 256, False, id="256-False"),
+    pytest.param(1024, 384, 256, True, id="256-True"),
+    pytest.param(1024, 384, 512, False, id="512-False"),
+    pytest.param(1024, 384, 512, True, id="512-True"),
+])
+def test_window_backward_matches_dense_oracle(s, window, block,
+                                              worker_parallel):
     """The block-sparse DASH backward under SlidingWindow, GQA 4/2 at head
-    dim 128, in both realizations, against the dense masked reference."""
+    dim 128, in both realizations, against the dense masked reference: at
+    the default tile and at 256 and 512 tiles, where a window of 384 leaves
+    PARTIAL tiles at both edges of the band."""
     ks = jax.random.split(jax.random.PRNGKey(6), 4)
-    q = jax.random.normal(ks[0], (1, 4, 512, 128), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 2, 512, 128), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 2, 512, 128), jnp.float32)
-    do = jax.random.normal(ks[3], (1, 4, 512, 128), jnp.float32)
-    mask = SlidingWindow(160)
+    q = jax.random.normal(ks[0], (1, 4, s, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2, s, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2, s, 128), jnp.float32)
+    do = jax.random.normal(ks[3], (1, 4, s, 128), jnp.float32)
+    mask = SlidingWindow(window)
 
     def grads(f):
         return jax.vjp(f, q, k, v)[1](do)
 
     got = grads(lambda a, b, c: ops.dash_attention(
-        a, b, c, mask=mask, interpret=True, worker_parallel=worker_parallel))
-    assert ops.last_tiling()["realization"] == (
+        a, b, c, mask=mask, interpret=True, block=block,
+        worker_parallel=worker_parallel))
+    rec = ops.last_tiling()
+    assert rec["realization"] == (
         "worker_parallel" if worker_parallel else "serialized")
+    assert block is None or rec["block"] == block
     with jax.default_matmul_precision("highest"):
         want = grads(lambda a, b, c: ops.xla_attention(a, b, c, mask=mask))
     for g, w in zip(got, want):

@@ -33,7 +33,8 @@ def test_footprint_discriminates_block_sizes():
 def test_footprint_counts_the_live_set_of_one_task(block, d):
     """The backward's four f32 (bq × bk) tiles s/p/dp/ds, the causal body's
     iotas and mask, and the dQ scratch at the 128-lane width the DMA moves
-    (not ``d``); the forward's s/p tiles. The full mask has no iotas."""
+    (not ``d``); the forward's s/p tiles. The full mask has no iotas; a
+    spec with token_info adds its double-buffered q column and k row."""
     tile = block * block * 4
     lanes = max(d, 128)
     causal = bwd_footprint(block, block, d, causal=True).buffers
@@ -42,6 +43,10 @@ def test_footprint_counts_the_live_set_of_one_task(block, d):
     assert causal["dq_scratch"] == block * lanes * 4
     assert "iotas/mask" not in bwd_footprint(block, block, d,
                                              causal=False).buffers
+    info = 2 * (block * 128 + 8 * block) * 4
+    for fp in (fwd_footprint, bwd_footprint):
+        assert fp(block, block, d, info=True).buffers["q/k info"] == info
+        assert "q/k info" not in fp(block, block, d).buffers
     fwd = fwd_footprint(block, block, d, causal=True).buffers
     assert fwd["s/p tiles"] == 2 * tile and fwd["iotas/mask"] == 3 * tile
     # the (bq × bk) tiles, not the blocks, dominate at 512
