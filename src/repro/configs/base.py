@@ -41,7 +41,6 @@ class ModelConfig:
     norm_eps: float = 1e-5
     activation: str = "silu"
     attention_impl: str = "xla"    # xla | pallas (DASH kernels)
-    dash_schedule: str = "symmetric_shift_or_shift"
     attn_chunk_q: int = 1024       # q-chunked attention above this seq (HBM bound)
     attn_window: int = 0           # sliding-window size in tokens (0 = full);
                                    # lowers as masks.SlidingWindow on both impls

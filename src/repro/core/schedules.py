@@ -435,10 +435,20 @@ def make_schedule(name: str, n: int, n_heads: int = 1, causal: bool = False,
     raise KeyError(f"unknown schedule {name!r}; available: {sorted(GENERATORS)}")
 
 
+def resolve_schedule(name: str, causal: bool, mask=None) -> str:
+    """The schedule a call runs: ``"symmetric_shift_or_shift"`` names the
+    paper's optimum for the mask, ``symmetric_shift`` when causal and
+    ``shift`` when full or under a block-sparse ``mask`` (where it is the
+    placement); any other name is returned as given."""
+    if name != "symmetric_shift_or_shift":
+        return name
+    return "symmetric_shift" if causal and mask is None else "shift"
+
+
 # Explicit bound on the shared schedule memo.  256 distinct (name, tiling,
-# mask) keys is ~an order of magnitude above what a training run plus a tuner
-# sweep touches; the bound exists so a pathological caller (e.g. a sweep over
-# thousands of masks) degrades to recompilation instead of unbounded growth.
+# mask) keys is ~an order of magnitude above what a training run touches;
+# the bound exists so a pathological caller (e.g. a sweep over thousands of
+# masks) degrades to recompilation instead of unbounded growth.
 # ``repro.masks.cache_info()`` exposes the hit/miss counters for the tracker.
 SCHEDULE_CACHE_MAXSIZE = 256
 
@@ -460,7 +470,7 @@ def _cached_schedule(name, n, n_heads, causal, n_q, mask, block_q, block_k):
 
 def cached_schedule(name: str, n: int, n_heads: int = 1, causal: bool = False,
                     n_q: int | None = None, mask=None, block_q: int = 128,
-                    block_k: int = 128, tune: bool = False) -> Schedule:
+                    block_k: int = 128) -> Schedule:
     """Memoized :func:`make_schedule` keyed by
     ``(name, n_kv=n_workers=n, n_q, n_heads, causal, mask, block_q, block_k)``.
 
@@ -477,17 +487,10 @@ def cached_schedule(name: str, n: int, n_heads: int = 1, causal: bool = False,
     :func:`repro.masks.schedule.cached_block_schedule` so both entry points
     hand out the *same* memoized instance per (mask, tiling, placement).
 
-    ``tune=True`` (block-sparse only) lets :func:`repro.tune.pick_placement`
-    resolve the placement from the modeled makespan instead of ``name`` — a
-    pure simulator comparison, so the choice is a function of the cache key,
-    never of wall-clock measurements.  The lru bound is
-    :data:`SCHEDULE_CACHE_MAXSIZE`; ``cached_schedule.cache_info()`` reports
-    hits/misses (surfaced by ``repro.masks.cache_info()``).
+    The lru bound is :data:`SCHEDULE_CACHE_MAXSIZE`;
+    ``cached_schedule.cache_info()`` reports hits/misses (surfaced by
+    ``repro.masks.cache_info()``).
     """
-    if tune and mask is not None:
-        from repro.tune import pick_placement
-        name = pick_placement(mask, n, n if n_q is None else n_q,
-                              block_q, block_k)
     # normalize to positional: lru_cache keys kwargs separately
     return _cached_schedule(name, n, n_heads, causal, n_q, mask,
                             block_q, block_k)

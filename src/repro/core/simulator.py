@@ -12,10 +12,9 @@ Operational semantics (matching the paper's Gantt charts):
 ``simulate`` returns the makespan plus utilization; ``closed_form`` returns the
 paper's analytic formulas so tests can assert exact agreement.
 
-This model is also the autotuner's ranking function: :mod:`repro.tune.model`
-scores every legal candidate with ``simulate`` at roofline-calibrated task
-costs, which is what makes sim-mode tuning a pure, bit-stable function of the
-geometry (no clock ever read).
+:func:`task_costs` gives ``c`` and ``r`` in seconds from the roofline of a
+TPU v5e-class chip; the modeled schedule lanes of
+:func:`repro.obs.export.attention_timeline` are drawn with it.
 """
 from __future__ import annotations
 
@@ -23,6 +22,20 @@ import dataclasses
 from typing import Dict, Optional, Tuple
 
 from repro.core.schedules import Schedule, Task
+
+# TPU v5e-class roofline constants — keep in sync with
+# benchmarks/bench_schedule_sim.rc_ratio (asserted by
+# tests/test_schedule_simulator_agreement.py)
+PEAK_FLOPS = 197e12
+HBM_BYTES_PER_S = 819e9
+
+
+def task_costs(block_q: int, block_k: int, head_dim: int) -> Tuple[float, float]:
+    """(c, r) seconds per task for one tile: 4 GEMMs of fwd+bwd-ish compute,
+    fp32 dQ block read+write for the reduction."""
+    c = (4 * 2 * block_q * block_k * head_dim) / PEAK_FLOPS
+    r = (2 * block_q * head_dim * 4) / HBM_BYTES_PER_S
+    return c, r
 
 
 @dataclasses.dataclass

@@ -10,10 +10,10 @@ injects bit-for-bit the same failures, so ``tests/test_chaos_conformance.py``
 can assert that every request completed under faults matches the fault-free
 run bitwise.
 
-Plans are content-addressed like :mod:`repro.tune.cache` records: ``key()``
-is ``faultplan-v{N}|sha256(canonical JSON)``, so a plan can name a
-conformance cell, a cached chaos artifact, or a CI matrix entry without any
-ambiguity about what was injected.
+Plans are content-addressed: ``key()`` is
+``faultplan-v{N}|sha256(canonical JSON)``, so a plan can name a conformance
+cell, a cached chaos artifact, or a CI matrix entry without any ambiguity
+about what was injected.
 
 Fault kinds (``site`` tells which layer consumes them):
 
@@ -108,8 +108,7 @@ class FaultPlan:
     def key(self) -> str:
         """Content address: two plans injecting the same faults share a key
         (``name`` is a display label, not content), and any fault edit — or a
-        PLAN_VERSION bump — changes it, the same contract as
-        ``tune.cache.make_key``."""
+        PLAN_VERSION bump — changes it."""
         content = json.dumps(
             {"plan_version": PLAN_VERSION,
              "faults": [f.to_dict() for f in self.faults]},

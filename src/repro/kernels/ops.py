@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.schedules import Schedule, cached_schedule, make_schedule
+from repro.core.schedules import cached_schedule, resolve_schedule
 from repro.kernels import ref as ref_mod
 from repro.kernels.flash_bwd import dq_bytes, flash_bwd, worker_grid
 from repro.kernels.flash_fwd import flash_fwd
@@ -130,7 +130,7 @@ def dash_attention(q, k, v, causal: bool = False,
                    schedule: str = "symmetric_shift_or_shift",
                    sm_scale: Optional[float] = None,
                    block: Optional[int] = None,
-                   interpret: bool = False, mask=None, tune=False,
+                   interpret: bool = False, mask=None,
                    worker_parallel: bool = True):
     """DASH attention with deterministic scheduled backward.
 
@@ -152,16 +152,10 @@ def dash_attention(q, k, v, causal: bool = False,
         head dim and the dtype); under a block-sparse ``mask``, also the
         largest whose computed pairs (its live tiles, PARTIAL ones whole)
         stay within ``vmem.MASK_PAIRS_MAX_RATIO`` of the 128 tiling's.
-      tune: ``True``/"sim" lets :func:`repro.tune.tune_attention` resolve
-        (schedule, block, worker_parallel) from the modeled makespan for this
-        (shape, dtype, mask) key; "measure" takes the winner of a measured
-        run from the tuner cache and raises when there is none.  Tuning only
-        *selects* knobs: the tuned call is bitwise identical to the
-        hand-configured call with the same resolved (schedule, block,
-        worker_parallel).
       worker_parallel: realize the backward across schedule worker chains
         (bitwise-equal to serialized when the schedule is single-visit;
-        auto-degrades otherwise).  Overridden by ``tune``.
+        :func:`repro.kernels.flash_bwd.worker_grid` degrades to serialized
+        otherwise).
     Returns: (B, H, S, D) attention output.
     """
     b, h, s, d = q.shape
@@ -178,21 +172,9 @@ def dash_attention(q, k, v, causal: bool = False,
             causal, mask = True, None
         else:
             assert not causal, "mask supersedes the causal flag"
-    if tune:
-        from repro.tune import tune_attention
-        result = tune_attention(seq=s, head_dim=d, dtype=q.dtype,
-                                causal=causal, mask=mask, n_heads=h,
-                                n_kv_heads=k.shape[1],
-                                mode=("sim" if tune is True else tune))
-        cand = result.candidate
-        schedule = cand.schedule
-        block = cand.block_q          # candidates are square-tiled
-        worker_parallel = cand.worker_parallel
-    elif block is None:
+    if block is None:
         block = best_block(s, d, q.dtype.itemsize, causal, mask=mask)
-    if schedule == "symmetric_shift_or_shift":
-        schedule = ("shift" if mask is not None else
-                    "symmetric_shift" if causal else "shift")
+    schedule = resolve_schedule(schedule, causal, mask)
     if mask is not None and schedule not in ("shift", "fa3"):
         raise ValueError(
             f"block-sparse masks take placement 'shift' or 'fa3'; got "
@@ -395,8 +377,7 @@ def _chunked(q, k, v, causal, sm_scale, chunk_q, score_eq, out_eq, mask=None,
 def attention(q, k, v, causal: bool = False, impl: str = "xla",
               schedule: str = "symmetric_shift_or_shift",
               sm_scale: Optional[float] = None, interpret: bool = False,
-              chunk_q: Optional[int] = None, mask=None, segment_ids=None,
-              tune=False):
+              chunk_q: Optional[int] = None, mask=None, segment_ids=None):
     """Model-facing dispatcher; see module docstring.
 
     Validates GQA group divisibility up front: q carries ``n_heads`` heads, k/v
@@ -417,5 +398,5 @@ def attention(q, k, v, causal: bool = False, impl: str = "xla",
                 "impl='pallas' takes no dynamic segment_ids: pass a static "
                 "mask=Document(...) or use impl='xla'")
         return dash_attention(q, k, v, causal, schedule, sm_scale,
-                              interpret=interpret, mask=mask, tune=tune)
+                              interpret=interpret, mask=mask)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -65,16 +65,11 @@ def main(argv=None):
                          "<ckpt-dir>/digest_chain.json or ./digest_chain.json)")
     ap.add_argument("--heartbeat", action="store_true",
                     help="enable straggler/hang monitor (launch/heartbeat.py)")
-    ap.add_argument("--tune", default="off", choices=["off", "sim"],
-                    help="resolve the attention schedule knobs with "
-                         "repro.tune before training: 'sim' ranks by modeled "
-                         "makespan (pure, reproducible). The choice is "
-                         "logged.")
     ap.add_argument("--track", default=None, metavar="JSONL",
                     help="write a repro.obs event stream here: per-step "
                          "throughput, fingerprint + divergence events (with "
-                         "--verify), tuner decisions, attention tilings, "
-                         "held-expert MoE routing counts")
+                         "--verify), attention tilings, held-expert MoE "
+                         "routing counts")
     ap.add_argument("--track-reference", default=None, metavar="JSONL",
                     help="a previous run's --track file; with --verify, the "
                          "live fingerprint stream is compared against it and "
@@ -120,16 +115,7 @@ def main(argv=None):
     tracker.log("run_config", {
         "arch": args.arch, "steps": args.steps, "batch": args.batch,
         "seq": args.seq, "microbatches": args.microbatches, "run_id": run_id,
-        "seed": args.seed, "tune": args.tune, "verify": bool(args.verify)})
-
-    if args.tune != "off":
-        from repro.tune import tune_attention
-        tres = tune_attention(seq=args.seq, head_dim=cfg.head_dim,
-                              dtype=cfg.dtype_name, causal=True,
-                              n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                              mode=args.tune, tracker=tracker)
-        print(f"[tune] {tres.candidate.key()} source={tres.source} "
-              f"modeled_makespan={tres.modeled_makespan_s:.3e}s", flush=True)
+        "seed": args.seed, "verify": bool(args.verify)})
 
     tcfg = S.TrainConfig(
         opt=O.OptConfig(name=args.opt, lr=args.lr, total_steps=args.steps),

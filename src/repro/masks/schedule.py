@@ -153,23 +153,14 @@ def _cached_block_schedule(mask, n_kv, n_q, block_q, block_k, placement):
 
 def cached_block_schedule(mask: MaskSpec, n_kv: int, n_q: int,
                           block_q: int = 128, block_k: int = 128,
-                          placement: str = "shift",
-                          tune: bool = False) -> Schedule:
+                          placement: str = "shift") -> Schedule:
     """Memoized :func:`compile_block_schedule`. The lru key includes the mask
     spec itself (hashable by construction), so two distinct masks with equal
     tile counts can never collide — the failure mode the old
     ``(name, n, n_heads, causal, n_q)`` key space allowed.
 
-    ``tune=True`` asks :func:`repro.tune.pick_placement` to choose the
-    placement from the modeled makespan (shift vs fa3 under the simulator) —
-    deterministic, because the comparison is a pure function of the mask's
-    block map, and sticky, because the resolved placement lands on the same
-    lru key a hand-picked call would.  The lru bound is
-    :data:`repro.core.schedules.SCHEDULE_CACHE_MAXSIZE`; hit/miss counters
-    surface through ``repro.masks.cache_info()``."""
-    if tune:
-        from repro.tune import pick_placement
-        placement = pick_placement(mask, n_kv, n_q, block_q, block_k)
+    The lru bound is :data:`repro.core.schedules.SCHEDULE_CACHE_MAXSIZE`;
+    hit/miss counters surface through ``repro.masks.cache_info()``."""
     return _cached_block_schedule(mask, n_kv, n_q, block_q, block_k, placement)
 
 

@@ -177,8 +177,8 @@ def _sdpa_full(q, k, v, cfg, causal, window=None, segment_ids=None):
         vt = shard(vt, "batch", None, None, None)
     with scope("attention"):
         out = attention_op(qt, kt, vt, causal=causal, impl=cfg.attention_impl,
-                           schedule=cfg.dash_schedule, chunk_q=cfg.attn_chunk_q,
-                           mask=mask, segment_ids=segment_ids)
+                           chunk_q=cfg.attn_chunk_q, mask=mask,
+                           segment_ids=segment_ids)
     if seq_shard:
         out = shard(out, "batch", None, "seq_sp", None)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)

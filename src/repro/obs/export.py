@@ -7,12 +7,11 @@ figures draw.  Three producers:
 
   * :func:`schedule_to_trace` — a ``core.schedules.Schedule`` as a
     **modeled** process: one thread per worker, task compute/reduce phases
-    at the simulator's ``(c, r)`` roofline costs — the exact DAG
-    ``tune/model.py`` ranks candidates with.  It is a model, not a
+    at the simulator's ``(c, r)`` roofline costs.  It is a model, not a
     measurement: the kernels' device time is read from a profiler trace.
   * :func:`attention_timeline` — convenience wrapper: build the schedule for
     a (seq, head_dim, mask) attention shape and cost it with
-    ``tune.model.task_costs``.
+    ``core.simulator.task_costs``.
   * :func:`spans_to_trace` — a recorded span stream (``repro.obs.span``
     events out of a tracker JSONL / ``MemoryTracker``) as one process with
     one thread per lane.
@@ -48,7 +47,7 @@ def schedule_to_trace(schedule, c: float, r: float,
     """Trace events for one schedule's modeled lanes.
 
     ``c``/``r`` are the simulator task costs in **seconds** (see
-    ``tune.model.task_costs``).  Returns a flat event list — wrap with
+    ``core.simulator.task_costs``).  Returns a flat event list — wrap with
     :func:`make_trace` / :func:`write_trace`.
     """
     from repro.core.simulator import simulate
@@ -86,14 +85,12 @@ def attention_timeline(seq: int, head_dim: int, *, causal: bool = True,
     Resolves the schedule like ``kernels.ops.dash_attention`` does and costs
     it with the roofline model.
     """
-    from repro.core.schedules import cached_schedule
-    from repro.tune.model import task_costs
+    from repro.core.schedules import cached_schedule, resolve_schedule
+    from repro.core.simulator import task_costs
 
     block = min(block, seq)
     n = max(1, seq // block)
-    name = schedule
-    if name == "symmetric_shift_or_shift":
-        name = "symmetric_shift" if causal else "shift"
+    name = resolve_schedule(schedule, causal, mask)
     sched = cached_schedule(name, n, n_heads=1, causal=causal, n_q=n,
                             mask=mask, block_q=block, block_k=block)
     c, r = task_costs(block, block, head_dim)

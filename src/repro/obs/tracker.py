@@ -1,7 +1,7 @@
 """Tracker protocol + the three standard sinks.
 
 A tracker is anything with ``log(event, data, step=None)`` and ``close()``.
-Producers (train loop, serving engine, tuner cache) call ``log`` with plain
+Producers (train loop, serving engine, kernel tilings) call ``log`` with plain
 scalars; the sink decides persistence.  The contract that keeps tracking out
 of the reproducibility story:
 

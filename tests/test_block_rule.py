@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.ops import dash_attention, last_tiling, log_tilings
-from repro.kernels.vmem import BLOCKS, MASK_PAIRS_MAX_RATIO, best_block
+from repro.kernels.vmem import (BLOCKS, MASK_PAIRS_MAX_RATIO, best_block,
+                                 bwd_footprint, fwd_footprint)
 from repro.masks.spec import Document, PrefixLM, SlidingWindow, streaming_mask
 from repro.obs import MemoryTracker
 
@@ -104,12 +105,39 @@ def test_paper_masks_count_their_pairs(causal, attended):
     assert rec["computed_pairs"] == (3 if causal else 4) * 512 * 512
 
 
-def test_tune_resolves_its_own_block():
-    from repro.tune import tune_attention
-    want = tune_attention(seq=1024, head_dim=64, dtype=jnp.bfloat16,
-                          causal=True, n_heads=4, n_kv_heads=4).candidate
-    rec = _trace(1024, 64, h=4, causal=True, tune=True)
-    assert (rec["block"], rec["schedule"]) == (want.block_q, want.schedule)
+def _fits(b, s, d, dtype_bytes, causal, mask):
+    info = mask is not None and mask.token_info(s) is not None
+    return all(fp(b, b, d, dtype_bytes, causal or mask is not None,
+                  info).fits(0.8) for fp in (fwd_footprint, bwd_footprint))
+
+
+def _within_pairs(b, s, mask):
+    return mask is None or (mask.pair_counts(s, b)[0] <= MASK_PAIRS_MAX_RATIO
+                            * mask.pair_counts(s, 128)[0])
+
+
+@pytest.mark.parametrize("kind", ["full", "causal", "window"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("d", [64, 128, 256])
+def test_rule_picks_the_largest_legal_tile(d, dtype, kind):
+    """Over the rule's domain, the tile a call records is ``best_block``'s,
+    divides S, has forward and backward footprints within the 0.8 budget,
+    and is the largest such: no larger tile of ``BLOCKS`` divides S, fits
+    and (under the window of S / 8) stays within ``MASK_PAIRS_MAX_RATIO``."""
+    nbytes, causal = jnp.dtype(dtype).itemsize, kind == "causal"
+    for s in (384, 640, 1024, 4096, 8192):
+        mask = SlidingWindow(s // 8) if kind == "window" else None
+        kw = dict(mask=mask) if mask is not None else dict(causal=causal)
+        block = _trace(s, d, dtype, **kw)["block"]
+        assert block == best_block(s, d, nbytes, causal, mask=mask), s
+        assert s % block == 0, s
+        assert _fits(block, s, d, nbytes, causal, mask), (s, block)
+        assert _within_pairs(block, s, mask), (s, block)
+        for b in BLOCKS:
+            if b > block and s % b == 0:
+                assert not (_fits(b, s, d, nbytes, causal, mask)
+                            and _within_pairs(b, s, mask)), (s, block, b)
 
 
 @pytest.mark.parametrize("causal,grid,real", [

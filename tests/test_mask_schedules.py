@@ -9,7 +9,7 @@ from repro.core import dag as dag_mod
 from repro.core import simulator as sim
 from repro.core.schedules import Schedule, cached_schedule, make_schedule
 from repro.masks import (Causal, Document, Full, PrefixLM, SlidingWindow,
-                         compile_block_schedule, ragged_columns,
+                         cache_info, compile_block_schedule, ragged_columns,
                          streaming_mask)
 
 C, R = 1.0, 0.5
@@ -190,3 +190,12 @@ def test_validate_catches_ragged_violations():
                        partial_cells=sch.partial_cells, mask_key=sch.mask_key)
     with pytest.raises(AssertionError, match="reduction orders"):
         broken2.validate()
+
+
+def test_masks_cache_info_exposed():
+    info = cache_info()
+    assert set(info) == {"cached_schedule", "cached_block_schedule",
+                         "block_map"}
+    for stats in info.values():
+        assert {"hits", "misses", "maxsize", "currsize"} <= set(stats)
+        assert stats["maxsize"] is not None      # explicit bound, never inf

@@ -10,6 +10,9 @@ The two load-bearing contracts:
     ``timestamps=False`` two identical runs produce byte-identical files.
 """
 import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -22,6 +25,8 @@ from repro.obs import (CompositeTracker, Counter, DivergenceAlarm, Histogram,
                        Timer, open_tracker, read_jsonl)
 from repro.obs.metrics import MetricSet
 from repro.serve.engine import ContinuousEngine, SampleConfig
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------------------------ trackers
@@ -200,3 +205,34 @@ def test_engine_tracker_token_invariance(serve_setup):
     assert mem.of("serve_decode")          # at least one batched decode step
     done = {e["request_id"]: e["n_tokens"] for e in mem.of("serve_done")}
     assert done == {i: len(plain[i]) for i in plain}
+
+
+# ------------------------------------------------------ launch smoke (slow)
+@pytest.mark.slow
+def test_launch_train_track_smoke(tmp_path):
+    """`--track --verify` end to end: the tracker JSONL carries per-step
+    throughput, the live fingerprint stream, and the cache/run summaries."""
+    track = tmp_path / "run.jsonl"
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch", "stablelm-1.6b",
+         "--reduced", "--steps", "3", "--batch", "2", "--seq", "128",
+         "--track", str(track), "--verify",
+         "--verify-out", str(tmp_path / "digest_chain.json")],
+        capture_output=True, text=True, timeout=600, cwd=REPO_ROOT,
+        env={**os.environ, "PYTHONPATH": "src"})
+    assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
+
+    events = [json.loads(l) for l in open(track)]
+    kinds = {e["event"] for e in events}
+    assert {"run_config", "step", "fingerprint", "cache_info",
+            "run_summary"} <= kinds
+    steps = [e for e in events if e["event"] == "step"]
+    assert [e["step"] for e in steps] == [1, 2, 3]
+    for e in steps:
+        assert e["tokens_per_s"] > 0
+        assert "utilization_vs_modeled" not in e
+        assert "loss" in e and "grad_norm" in e
+    # the fingerprint chain stayed clean
+    assert not [e for e in events if e["event"] == "fingerprint_divergence"]
+    summary = next(e for e in events if e["event"] == "run_summary")
+    assert summary["fingerprint_ok"] is True

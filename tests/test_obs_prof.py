@@ -295,8 +295,7 @@ def test_schedule_timeline_validates_with_both_lanes():
     """The modeled lane: one thread per worker, every task's compute and
     reduce phase at the simulator's times, and no other process."""
     from repro.core.schedules import cached_schedule
-    from repro.core.simulator import simulate
-    from repro.tune.model import task_costs
+    from repro.core.simulator import simulate, task_costs
     n = 128 // 64
     sched = cached_schedule("symmetric_shift", n, 1, True, n)
     c, r = task_costs(64, 64, 32)

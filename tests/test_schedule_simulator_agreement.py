@@ -6,10 +6,16 @@ the paper's closed form, and the schedule must satisfy Schedule.validate().
 Also covers the rectangular-grid path through the uniform make_schedule entry
 point (n_q forwarding).
 """
+import os
+import sys
+
 import pytest
 
 from repro.core import schedules as S
 from repro.core import simulator as sim
+from repro.core.simulator import task_costs
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # compute-bound cost point: every closed form is exact here (descending's
 # formula only holds for c >= r — see test_core_schedules for the r > c band).
@@ -72,3 +78,14 @@ def test_make_schedule_rejects_n_q_on_square_generators():
         S.make_schedule("descending", 4, causal=True, n_q=8)
     # n_q == n is the square case and stays accepted
     S.make_schedule("symmetric_shift", 4, n_heads=2, causal=True, n_q=4).validate()
+
+
+def test_calibration_matches_bench_schedule_sim():
+    sys.path.insert(0, REPO_ROOT)
+    try:
+        from benchmarks.bench_schedule_sim import rc_ratio
+    finally:
+        sys.path.remove(REPO_ROOT)
+    for d in (64, 128):
+        c, r = task_costs(128, 128, d)
+        assert r / c == pytest.approx(rc_ratio(d, 128))
