@@ -90,8 +90,9 @@ class ModelConfig:
     dtype_name: str = "bfloat16"
     vocab_pad: int = 2048                   # pad vocab to multiple of tp*128
     scan_unroll: bool = False               # unroll the layer scan (cost probes)
-    det_embed_grad: bool = True    # embedding bwd as pinned one-hot matmul
-                                   # (no unordered scatter-add); False restores
+    det_embed_grad: bool = True    # embedding bwd as a sorted, fixed-order
+                                   # segment sum gathered once a row (no
+                                   # unordered scatter-add); False restores
                                    # the gather-grad scatter — flagged by
                                    # repro.verify.trace
     canonical_reductions: int = 0  # 0 = fused XLA reductions (training

@@ -393,23 +393,56 @@ def embed_defs(cfg):
     return {"tok": PD((cfg.padded_vocab, cfg.d_model), ("vocab", "embed"))}
 
 
-# one-hot transient budget for the deterministic embedding backward:
-# block = ~2^25 fp32 elements (~128 MB) regardless of vocab size
-_EMBED_BWD_ELEMS = 1 << 25
+# sorted rows that one product of the run sums adds: the v5e MXU's edge
+_RUN_BLOCK = 128
+
+
+def _segment_total(a, b):
+    """Combine of the segmented sum over token-sorted rows: ``(key, value)``
+    is a range's last token id and the sum of that range's tail run of it.
+    Sorted keys make ``ka == kb`` mean the whole right range is one run."""
+    ka, va = a
+    kb, vb = b
+    return kb, jnp.where((ka == kb)[:, None], va + vb, vb)
+
+
+def _run_totals(keys, rows):
+    """fp32 sums of each run of equal sorted ``keys``, each on its run's last
+    row. A block of ``_RUN_BLOCK`` rows is summed by one product with its 0/1
+    "same key, not later" mask: exact products, fp32 accumulation in an order
+    fixed at compile time. A run that enters a block from earlier ones adds
+    their tail-run total, a segmented ``associative_scan`` over the blocks'
+    last rows."""
+    t, d = rows.shape
+    b = min(_RUN_BLOCK, t)
+    pad = -t % b
+    kb = jnp.pad(keys, (0, pad), constant_values=jnp.iinfo(keys.dtype).max
+                 ).reshape(-1, b)
+    rb = jnp.pad(rows, ((0, pad), (0, 0))).reshape(-1, b, d)
+    same = (kb[:, :, None] == kb[:, None, :]) & jnp.tri(b, dtype=bool)
+    part = jnp.einsum("nij,njd->nid", same.astype(rows.dtype), rb,
+                      preferred_element_type=F32,
+                      precision=jax.lax.Precision.HIGHEST)
+    _, tail = jax.lax.associative_scan(_segment_total, (kb[:, -1], part[:, -1]))
+    carry = jnp.pad(tail[:-1], ((1, 0), (0, 0)))
+    entering = kb == jnp.pad(kb[:-1, -1], (1, 0), constant_values=-1)[:, None]
+    part = part + jnp.where(entering[..., None], carry[:, None], 0.0)
+    return part.reshape(-1, d)[:t]
 
 
 @functools.lru_cache(maxsize=None)
 def _det_embed_lookup(vocab: int, dtype_name: str):
     """Embedding lookup with a deterministic backward.
 
-    dtable = scatter-add(dy at tokens) ≡ one_hot(tokens)ᵀ @ dy, but the
-    matmul's reduction association is pinned at compile time on every
-    backend, where the scatter-add reduces duplicate tokens in
-    backend-defined order (GPU atomics — the Fig. 1 baseline). fp32
-    accumulation as everywhere. The token axis is processed in fixed-size
-    blocks (ascending scan, ~128 MB one-hot transient each) so the
-    determinism doesn't cost a (B·S, V) allocation at full vocab; block
-    padding uses index == vocab, whose one-hot row is all-zero.
+    dtable = scatter-add(dy at tokens), but a scatter-add reduces duplicate
+    tokens in backend-defined order (GPU atomics — the Fig. 1 baseline).
+    Here each table row is written exactly once, by a gather: a stable
+    argsort groups equal tokens (ties by batch position), ``_run_totals``
+    sums each run in fp32 in an association fixed at compile time (the total
+    lands on the run's last element), and row ``v`` gathers its run's total
+    through a ``searchsorted`` over the sorted ids; rows no token hit gather
+    an exact zero row. O(T·d + V·d) for T tokens: no one-hot, no
+    vocabulary-sized matmul, and only the shapes decide the program.
     """
     dtype = jnp.dtype(dtype_name)
 
@@ -420,36 +453,18 @@ def _det_embed_lookup(vocab: int, dtype_name: str):
     def fwd(table, tokens):
         return table[tokens], tokens
 
-    def block_grad(tok_blk, dy_blk):
-        onehot = jax.nn.one_hot(tok_blk, vocab, dtype=F32)
-        return jax.lax.dot_general(onehot, dy_blk, (((0,), (0,)), ((), ())),
-                                   preferred_element_type=F32)
-
     def bwd(tokens, dy):
         flat_tok = tokens.reshape(-1)
-        flat_dy = dy.reshape(-1, dy.shape[-1]).astype(F32)
-        t = flat_tok.shape[0]
-        block = min(t, max(64, _EMBED_BWD_ELEMS // vocab))
-        n_blocks = -(-t // block)
-        if n_blocks == 1:
-            dtable = block_grad(flat_tok, flat_dy)
-        else:
-            pad = n_blocks * block - t
-            if pad:
-                flat_tok = jnp.concatenate(
-                    [flat_tok, jnp.full((pad,), vocab, flat_tok.dtype)])
-                flat_dy = jnp.concatenate(
-                    [flat_dy, jnp.zeros((pad, flat_dy.shape[1]), F32)])
-
-            def acc(dtable, blk):
-                tok_blk, dy_blk = blk
-                return dtable + block_grad(tok_blk, dy_blk), None
-
-            dtable, _ = jax.lax.scan(
-                acc, jnp.zeros((vocab, flat_dy.shape[1]), F32),
-                (flat_tok.reshape(n_blocks, block),
-                 flat_dy.reshape(n_blocks, block, -1)))
-        return dtable.astype(dtype), np.zeros(tokens.shape, jax.dtypes.float0)
+        order = jnp.argsort(flat_tok, stable=True)
+        keys = flat_tok[order]
+        totals = _run_totals(keys, dy.reshape(-1, dy.shape[-1])[order])
+        totals = jnp.concatenate(
+            [totals.astype(dtype), jnp.zeros((1, totals.shape[1]), dtype)])
+        ids = jnp.arange(vocab, dtype=keys.dtype)
+        last = jnp.searchsorted(keys, ids, side="right") - 1
+        hit = keys[jnp.maximum(last, 0)] == ids
+        dtable = totals[jnp.where(hit, last, keys.shape[0])]
+        return dtable, np.zeros(tokens.shape, jax.dtypes.float0)
 
     lookup.defvjp(fwd, bwd)
     return lookup

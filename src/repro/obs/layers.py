@@ -11,8 +11,8 @@ trace's device ops can be summed per layer by instruction name.
 
 The vocabulary (where each scope sits):
 
-  ``embed``      the token embedding, with its one-hot backward
-                 (``models/layers.apply_embed``);
+  ``embed``      the token embedding, with its sorted, gather-only
+                 backward (``models/layers.apply_embed``);
   ``attn_proj``  the pre-norm, q/k/v/o projections, biases and rotary of an
                  attention sublayer, not the attention kernel;
   ``attention``  the attention kernel call (``kernels.ops.attention`` in

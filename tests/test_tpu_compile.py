@@ -5,7 +5,8 @@ interpret-mode test and still be refused by the chip's compiler (block
 layouts, DMA tiling). Each case here compiles ahead of time for one chip of a
 described ``v5e:2x2`` topology, at stablelm-1.6b attention widths (BH 32,
 S 4096, D 64, bf16), and asserts the Mosaic kernel (``tpu_custom_call``) is
-in the compiled program.
+in the compiled program. The embedding backward's case asserts instead that
+no scatter and no vocabulary-long convolution is left in it.
 
 The topology is described inside a fixture, never while a module is imported:
 only one process may load the TPU library, and every test worker imports
@@ -60,7 +61,7 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_for(one_chip):
+def aot(one_chip):
     """Compile ``fn`` at the given shapes for one described chip, with the
     persistent compilation cache off: an entry written for a chip that is
     not attached cannot be read back."""
@@ -72,13 +73,22 @@ def compile_for(one_chip):
     def run(fn, *shapes):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape, dtype in shapes]
-        compiled = jax.jit(fn).lower(*args).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-        return compiled
+        return jax.jit(fn).lower(*args).compile()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", prev)
     cc.reset_cache()
+
+
+@pytest.fixture
+def compile_for(aot):
+    """``aot``, asserting a Mosaic kernel is in the compiled program."""
+    def run(fn, *shapes):
+        compiled = aot(fn, *shapes)
+        assert "tpu_custom_call" in compiled.as_text()
+        return compiled
+
+    return run
 
 
 def _schedule(causal, block):
@@ -156,6 +166,31 @@ def test_attention_scope_names_every_kernel(compile_for):
     assert kernels and {layers[k] for k in kernels} == {"attention"}
     for name in ("flash_fwd", "_flash_bwd_worker_call", "_fold_combine_call"):
         assert any(re.match(rf"{name}\b", k) for k in kernels), (name, kernels)
+
+
+def test_embed_grad_compiles_without_scatter_or_vocab_matmul(aot):
+    """``grad`` through ``apply_embed`` at train-stablelm-4k's shape (2 x
+    4096 tokens, 100,352 x 2048 table, bf16 cotangent) compiles for one v5e
+    with no scatter and no convolution over a 100,352-long axis: each table
+    row is gathered once, never summed by a one-hot product."""
+    import re
+
+    from repro.configs import registry
+    from repro.models import layers as L
+
+    cfg = registry.get("stablelm-1.6b")
+    v, d = cfg.padded_vocab, cfg.d_model
+
+    def loss(table, tokens, dy):
+        emb = L.apply_embed({"tok": table}, tokens, cfg)
+        return jnp.sum(emb.astype(jnp.float32) * dy.astype(jnp.float32))
+
+    hlo = aot(jax.grad(loss), ((v, d), jnp.float32), ((2, 4096), jnp.int32),
+              ((2, 4096, d), jnp.bfloat16)).as_text()
+    assert v == 100_352
+    assert not re.search(r"\bscatter\(", hlo)
+    convs = re.findall(r"^.*\bconvolution\(.*$", hlo, re.M)
+    assert not [c for c in convs if str(v) in c.split("convolution(")[0]], convs
 
 
 # Mellum2-12B-A2.5B's attention: GQA 32/4 at head dim 128, S 8192, two rows
